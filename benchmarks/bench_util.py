@@ -46,3 +46,8 @@ def time_fn(fn, *args, warmup: int = 1, iters: int = 5) -> float:
 
 def emit(name: str, us: float, derived: str = "") -> None:
     print(f"{name},{us:.1f},{derived}")
+
+
+def host_info() -> dict:
+    """The JAX version and the platform of the device the run used."""
+    return {"jax": jax.__version__, "device": jax.devices()[0].platform}

@@ -1,4 +1,6 @@
 import os
+# A CPU-only compile study: this overwrites XLA_FLAGS at import to fake
+# 512 host devices, so it is never a chip entry point (chip_smoke.py is).
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """§Perf hillclimb driver — compiles the OPTIMIZED variants of the three

@@ -18,6 +18,11 @@ summary row — rsb_batched vs rsb_recursive wall clock — the
 `partition_time_smoke` baseline the CI gate compares against, and the
 `partition_large` multilevel-vs-spectral head-to-head rows the gate's
 check_multilevel reads) to PATH in the BENCH_partition.json layout.
+
+This process never imports JAX: every suite runs in a fresh child, one
+after another, so each child has the device to itself (a process that has
+initialised JAX holds the chip, and a child that needs it then fails or
+hangs).
 """
 
 from __future__ import annotations
@@ -30,40 +35,51 @@ import subprocess
 import sys
 import time
 
+_ROWS = "ROWS="
+
+
+def _in_child(module: str, call: str, *, cache: bool = True):
+    """Run ``benchmarks.<module>.<call>`` in a fresh process; echo its
+    stdout (the CSV rows) and return the JSON-decoded result.
+    ``cache=False`` leaves the persistent compilation cache off, which is
+    how smoke_check runs."""
+    code = ("import json, sys\n"
+            + ("from repro.compile_cache import use_compile_cache\n"
+               "use_compile_cache()\n" if cache else "")
+            + f"from benchmarks import {module} as m\n"
+            f"rows = m.{call}\n"
+            "sys.stdout.flush()\n"
+            f"print({_ROWS!r} + json.dumps(rows, default=str))\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=dict(os.environ))
+    sys.stderr.write(out.stderr)
+    rows, found = None, False
+    for line in out.stdout.splitlines():
+        if line.startswith(_ROWS):
+            rows, found = json.loads(line[len(_ROWS):]), True
+        else:
+            print(line)
+    if out.returncode != 0 or not found:
+        raise SystemExit(f"benchmark child {module}.{call} failed "
+                         f"(exit {out.returncode})")
+    return rows
+
 
 def _smoke_baseline_rows(repeats: int = 3) -> list:
     """Measure the partition_time smoke rows the way benchmarks.smoke_check
     will gate them: a FRESH process per repetition running the gate's exact
-    recipe (one cold run that pays the XLA compiles, then the min-sum of
-    three warm runs), keeping the repetition with the minimal summed wall
-    clock.  Matching the estimator on both sides is the whole point:
-    per-row minima across repetitions would bound below anything a single
-    run can reach, and measuring in the warm tail of the full suite reads
-    ~25-30% faster than any fresh smoke_check process — either way the
-    wall gate's headroom would be spent on methodology, not regressions."""
-    from benchmarks.smoke_check import _wall_rows
-
-    code = (
-        "import json, sys\n"
-        "from benchmarks import partition_time\n"
-        "from benchmarks.smoke_check import _wall_rows\n"
-        "partition_time.run(smoke=True)\n"
-        "warm = [partition_time.run(smoke=True) for _ in range(3)]\n"
-        "best = min(warm,\n"
-        "           key=lambda rs: sum(r['seconds'] for r in _wall_rows(rs)))\n"
-        "sys.stdout.flush()\n"
-        "print('ROWS=' + json.dumps(best))\n"
-    )
-    runs = []
-    for _ in range(repeats):
-        out = subprocess.run(
-            [sys.executable, "-c", code], capture_output=True, text=True,
-            check=True, env=dict(os.environ),
-        )
-        line = [l for l in out.stdout.splitlines() if l.startswith("ROWS=")]
-        runs.append(json.loads(line[-1][len("ROWS="):]))
-    return min(runs,
-               key=lambda rows: sum(r["seconds"] for r in _wall_rows(rows)))
+    recipe (``smoke_check.measure_smoke``: one cold run that pays the XLA
+    compiles, then the min-sum of three warm runs), keeping the repetition
+    with the minimal summed wall clock.  Matching the estimator on both
+    sides is the whole point: per-row minima across repetitions would
+    bound below anything a single run can reach, and measuring in the warm
+    tail of the full suite reads ~25-30% faster than any fresh smoke_check
+    process — either way the wall gate's headroom would be spent on
+    methodology, not regressions.  The gate runs without the persistent
+    compilation cache, so these children do too."""
+    runs = [_in_child("smoke_check", "measure_smoke()[1:]", cache=False)
+            for _ in range(repeats)]
+    return min(runs, key=lambda warm_wall: warm_wall[1])[0]
 
 
 def _engine_pre_table(partition_rows) -> list:
@@ -167,13 +183,9 @@ def main() -> None:
     large_rows: list = []
     sharded_rows: list = []
     if want("quality"):
-        from benchmarks import quality
-
-        quality_rows = quality.run(full=args.full)
+        quality_rows = _in_child("quality", f"run(full={args.full})")
     if want("partition_time"):
-        from benchmarks import partition_time
-
-        partition_rows = partition_time.run(full=args.full)
+        partition_rows = _in_child("partition_time", f"run(full={args.full})")
         for line in _engine_pre_table(partition_rows):
             print(line)
         if args.json:
@@ -183,35 +195,23 @@ def main() -> None:
             # Large-mesh engine head-to-head behind the multilevel claim;
             # smoke_check gates these recorded rows instead of re-running
             # the ~10x mesh on every push.
-            large_rows = partition_time.run_large()
+            large_rows = _in_child("partition_time", "run_large()")
             # Device-resident sharded refinement vs the host chain from
             # the same bisection — check_dist_refine gates cut parity and
             # the one-collective-per-sweep contract on these rows.
-            sharded_rows = partition_time.run_sharded()
-    if want("weak_scaling"):
-        from benchmarks import weak_scaling
-
-        weak_scaling.run(full=args.full)
-    if want("kernels"):
-        from benchmarks import kernels
-
-        kernels.run(full=args.full)
+            sharded_rows = _in_child("partition_time", "run_sharded()")
+    for name in ("weak_scaling", "kernels"):
+        if want(name):
+            _in_child(name, f"run(full={args.full})")
     if want("roofline"):
-        from benchmarks import roofline_table
-
-        roofline_table.run(args.dryrun_dir)
+        _in_child("roofline_table", f"run({args.dryrun_dir!r})")
 
     if args.json:
-        import jax
-
         payload = {
             "date": time.strftime("%Y-%m-%d"),
-            "host": {
-                "platform": platform.platform(),
-                "python": platform.python_version(),
-                "jax": jax.__version__,
-                "device": jax.devices()[0].platform,
-            },
+            "host": {"platform": platform.platform(),
+                     "python": platform.python_version(),
+                     **_in_child("bench_util", "host_info()")},
             "quality": quality_rows,
             "partition_time": partition_rows,
             "partition_time_smoke": smoke_rows,

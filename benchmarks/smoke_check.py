@@ -105,6 +105,18 @@ def _wall_rows(rows) -> list:
     return refined or list(rows)
 
 
+def measure_smoke() -> tuple[list, list, float]:
+    """The gate's estimator: one cold smoke run (it pays the XLA compiles
+    and its rows gate the cut), then three warm runs, keeping the one
+    with the minimal summed wall.  Returns ``(cold, warm, warm_wall)``;
+    ``benchmarks.run --json`` records its baseline with this same call."""
+    cold = partition_time.run(smoke=True)
+    warm_runs = [partition_time.run(smoke=True) for _ in range(3)]
+    warm = min(warm_runs,
+               key=lambda rs: sum(r["seconds"] for r in _wall_rows(rs)))
+    return cold, warm, sum(r["seconds"] for r in _wall_rows(warm))
+
+
 def check_refine_invariants(rows, warm_rows=None) -> list:
     """The post-stage contract, asserted within one run: refined cut never
     above raw cut, zero disconnected parts, bounded post wall clock.
@@ -411,13 +423,10 @@ def main() -> int:
               file=sys.stderr)
         return 1
 
-    rows = partition_time.run(smoke=True)        # cold: gates the cut
-    # warm: min-of-3 summed wall clock (same estimator as the baseline);
-    # the min-sum run's rows also feed the post-fraction invariant
-    warm_runs = [partition_time.run(smoke=True) for _ in range(3)]
-    warm = min(warm_runs,
-               key=lambda rs: sum(r["seconds"] for r in _wall_rows(rs)))
-    wall = sum(r["seconds"] for r in _wall_rows(warm))
+    # cold rows gate the cut; warm: min-of-3 summed wall clock (same
+    # estimator as the baseline); the min-sum run's rows also feed the
+    # post-fraction invariant
+    rows, warm, wall = measure_smoke()
     by_key = {_key(r): r for r in rows}
     failed = False
     for base in base_rows:

@@ -13,11 +13,13 @@ import jax
 import numpy as np
 from jax.sharding import AxisType
 
+from repro.compile_cache import use_compile_cache
 from repro.core import PartitionPipeline, partition_metrics
 from repro.core.rcb import rcb_parts
 from repro.dist.partition_aware import adjacency_matvec_distributed, plan_halo_sharding
 from repro.mesh.graphs import grid_graph_2d
 
+use_compile_cache()
 n_shards = 8
 g = grid_graph_2d(32, 32)
 coords = np.stack(np.meshgrid(np.arange(32), np.arange(32), indexing="ij"),

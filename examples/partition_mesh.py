@@ -35,6 +35,7 @@ if _ndev > 1 and "xla_force_host_platform_device_count" not in \
 
 
 from repro import obs
+from repro.compile_cache import use_compile_cache
 from repro.core import PartitionPipeline, partition, partition_metrics, run_post_stages
 from repro.dist.partition_aware import plan_halo_sharding, scatter_features
 from repro.guard import GuardError, check_positive_int, validate_mesh, validate_nparts
@@ -42,6 +43,7 @@ from repro.mesh import dual_graph, pebble_mesh
 
 
 def main(argv=None) -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--dims", nargs=3, default=[12, 12, 12],
                     metavar=("NX", "NY", "NZ"))

@@ -20,8 +20,4 @@ configs/   one config per assigned architecture (+ the paper's own).
 launch/    production mesh, multi-pod dry-run, roofline extraction.
 """
 
-from repro import _jax_compat as _jax_compat
-
-_jax_compat.install()
-
 __version__ = "1.0.0"

@@ -447,7 +447,8 @@ def run_post_stages(
         agg.seconds += dt
         records.append(StageRecord(
             kind="post", name=name, seconds=dt,
-            info={"cut_before": stats.cut_before,
+            info={"stages": list(stats.stages),
+                  "cut_before": stats.cut_before,
                   "cut_after": stats.cut_after,
                   "fragments": stats.fragments_repaired,
                   "moves": stats.moves_applied,

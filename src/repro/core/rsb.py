@@ -59,6 +59,7 @@ from repro.core.fiedler import (
     next_pow2,
 )
 from repro.core.rcb import rcb_order, rib_order
+from repro.guard.errors import SolverBreakdown
 from repro.guard.policy import SolverGuard
 from repro.mesh.graphs import Graph, dual_graph_from_incidence, extract_subgraphs
 
@@ -353,7 +354,7 @@ def _rsb_mesh_recursive(
                 if not sg.expired():  # past the stage deadline: skip straight
                     try:              # to the fallback rung inside rescue
                         res = solve_fn(method, _node_seed(seed, level, p_lo))
-                    except Exception:
+                    except SolverBreakdown:
                         res = None
                 res = _guarded(sg, res, solve_fn, level=level, p_lo=p_lo,
                                size=int(idx.size),
@@ -511,7 +512,7 @@ def _rsb_graph_recursive(
                 if not sg.expired():  # past the stage deadline: skip straight
                     try:              # to the fallback rung inside rescue
                         res = solve_fn(method, _node_seed(seed, level, p_lo))
-                    except Exception:
+                    except SolverBreakdown:
                         res = None
                 res = _guarded(
                     sg, res, solve_fn, level=level, p_lo=p_lo,

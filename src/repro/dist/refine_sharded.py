@@ -54,10 +54,11 @@ the host only sees per-sweep scalars (moves, realized gain, pending).
 on integer-weight meshes the device and host paths produce identical
 labels.  The pipeline stages (``refine-sharded``, ``kway-sharded``) wrap
 the sweep loop with the guard envelope — ``plan_halo_sharding`` already
-self-heals ``halo_truncate`` chaos, an expired ``SolverGuard`` deadline
-or any device-path failure degrades to the host FM refiner (counted in
-``guard_fallbacks``) — and close with a repair pass so the
-zero-disconnected-parts invariant survives articulation moves.
+self-heals ``halo_truncate`` chaos; an expired ``SolverGuard`` deadline,
+or a result the stage rejects (invalid labels, a higher cut), degrades to
+the host FM refiner (counted in ``guard_fallbacks``), while an exception
+from the device path itself propagates — and close with a repair pass so
+the zero-disconnected-parts invariant survives articulation moves.
 """
 
 from __future__ import annotations
@@ -68,6 +69,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
@@ -80,6 +82,7 @@ from repro.core.refine import (
     refine_boundary,
 )
 from repro.dist.partition_aware import HaloPlan, plan_halo_sharding, scatter_features
+from repro.guard.errors import RefineRejected
 from repro.kernels.segment_sum.ops import connection_table_batched
 
 EPS = 1e-6   # strict-positive-gain threshold (f32-safe)
@@ -342,15 +345,16 @@ def _device_step(fp: FrontierPlan, nparts: int, floor: float, cap: float,
         out_specs=(spec,) * 6,
         check_vma=False,
     ))
-    consts = (
-        jnp.asarray(fp.exp_slot), jnp.asarray(fp.exp_slot_sc),
-        jnp.asarray(fp.exp_mask), jnp.asarray(fp.exp_w),
-        jnp.asarray(fp.exp_gid), jnp.asarray(fp.ell_cols),
-        jnp.asarray(fp.ell_wts), jnp.asarray(fp.nbr_prow),
-        jnp.asarray(fp.node_w), jnp.asarray(fp.node_mask),
-        jnp.asarray(fp.exp_gid.reshape(-1)),
-        jnp.asarray(fp.exp_w.reshape(-1)),
-    )
+    # Each constant goes straight to its devices with the sharding the
+    # step expects: staged through the default device first, device 0
+    # would keep an unsharded copy of the whole plan.
+    shard = NamedSharding(mesh, spec)
+    whole = NamedSharding(mesh, rep)
+    consts = tuple(jax.device_put(a, shard) for a in (
+        fp.exp_slot, fp.exp_slot_sc, fp.exp_mask, fp.exp_w, fp.exp_gid,
+        fp.ell_cols, fp.ell_wts, fp.nbr_prow, fp.node_w, fp.node_mask,
+    )) + tuple(jax.device_put(a, whole) for a in (
+        fp.exp_gid.reshape(-1), fp.exp_w.reshape(-1)))
     return fn, consts, mesh
 
 
@@ -392,11 +396,15 @@ def run_sharded_sweeps(fp: FrontierPlan, parts: np.ndarray, nparts: int, *,
                                    corridor=corridor)
     floor, cap = float(corridor[0]), float(corridor[1])
     n_dev = _pick_devices(plan.n_shards, max_devices)
-    fn, consts, _mesh = _device_step(fp, nparts, floor, cap, n_dev)
+    fn, consts, mesh = _device_step(fp, nparts, floor, cap, n_dev)
 
-    labels = jnp.asarray(scatter_features(plan, parts).astype(np.int32))
-    pgain = jnp.full((plan.n_shards, plan.halo), -1.0, jnp.float32)
-    ptgt = jnp.full((plan.n_shards, plan.halo), -1, jnp.int32)
+    shard = NamedSharding(mesh, P("shards"))
+    labels = jax.device_put(scatter_features(plan, parts).astype(np.int32),
+                            shard)
+    pgain = jax.device_put(
+        np.full((plan.n_shards, plan.halo), -1.0, np.float32), shard)
+    ptgt = jax.device_put(
+        np.full((plan.n_shards, plan.halo), -1, np.int32), shard)
 
     records, total_moves, gathers, cut = [], 0, 0, cut0
     words = plan.n_shards * fp.gather_row_words
@@ -573,7 +581,9 @@ def refine_sharded_host(fp: FrontierPlan, parts: np.ndarray, nparts: int, *,
 def _sharded_pass(graph, parts, nparts, *, weights, sweeps, corridor,
                   backend, guard, stats: PostStats):
     """Shared core of the two stages: guard envelope → sharded sweeps →
-    fall back to the host FM refiner on any device-path failure."""
+    fall back to the host FM refiner when the guard rejects the result.
+    Exceptions from the device path itself (compile, lowering, runtime,
+    out of memory) propagate: they are faults, not degraded results."""
     parts = np.asarray(parts, dtype=np.int64)
     if guard is not None and getattr(guard, "expired", lambda: False)():
         # guard.expired() itself emits guard_deadline_expired on first trip.
@@ -588,18 +598,18 @@ def _sharded_pass(graph, parts, nparts, *, weights, sweeps, corridor,
         out = np.asarray(out, dtype=np.int64)
         if (out.shape != parts.shape or out.min() < 0
                 or out.max() >= nparts):
-            raise ValueError("sharded refinement produced invalid labels")
+            raise RefineRejected("sharded refinement produced invalid labels")
         cut_now = edge_cut(graph, out)
         if cut_now > stats.cut_before + 1e-6:
-            raise ValueError(
+            raise RefineRejected(
                 f"sharded refinement increased the cut "
                 f"({stats.cut_before} -> {cut_now})")
         stats.sweeps.extend(records)
         stats.moves_applied += info["moves"]
         return out, True
-    except Exception:
-        # Guard escalation: the exchange/sweep path failed — degrade to
-        # the host FM refiner rather than ship a corrupt partition.
+    except RefineRejected:
+        # Guard escalation: the sweep result failed the stage's checks —
+        # degrade to the host FM refiner rather than ship it.
         obs.counter_add("guard_fallbacks", 1)
         stats.stages.append("host-fallback")
         out, fstats = refine_boundary(graph, parts, nparts, weights=weights,

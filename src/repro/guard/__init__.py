@@ -8,7 +8,13 @@ modes & degradation ladder") for the full contract.
 """
 
 from repro.guard import chaos
-from repro.guard.errors import GuardError, GuardIssue, GuardReport
+from repro.guard.errors import (
+    GuardError,
+    GuardIssue,
+    GuardReport,
+    RefineRejected,
+    SolverBreakdown,
+)
 from repro.guard.policy import (
     GuardPolicy,
     SolverGuard,
@@ -29,7 +35,8 @@ from repro.guard.validate import (
 )
 
 __all__ = [
-    "GuardError", "GuardIssue", "GuardReport", "chaos",
+    "GuardError", "GuardIssue", "GuardReport", "RefineRejected",
+    "SolverBreakdown", "chaos",
     "check_positive_int", "component_labels", "pack_components",
     "proportional_budgets", "validate_graph", "validate_mesh",
     "validate_nparts", "GuardPolicy", "SolverGuard", "check_output",

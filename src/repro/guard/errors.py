@@ -1,6 +1,8 @@
 """Typed guard diagnostics.
 
-One exception class, many machine-readable codes.  ``GuardError`` is what
+One exception class, many machine-readable codes, plus two subclasses the
+guard's fallbacks catch (:class:`SolverBreakdown`, :class:`RefineRejected`)
+so no fallback has to catch a bare ``Exception``.  ``GuardError`` is what
 the validation front door raises in strict mode and what the CLI entry
 points catch and pretty-print — ``code`` is a stable kebab-case slug a
 caller can branch on, ``details`` carries the numbers (offending counts,
@@ -26,6 +28,8 @@ KNOWN_CODES = (
     "bad-node-weight",
     # mesh
     "empty-mesh",
+    # stage outcomes the guard degrades around
+    "solver-breakdown", "refine-rejected",
 )
 
 
@@ -54,6 +58,28 @@ class GuardError(ValueError):
         lines.append("  (fix the input, or pass sanitize=True to let the "
                      "guard repair what is repairable)")
         return "\n".join(lines)
+
+
+class SolverBreakdown(GuardError):
+    """A Fiedler solve that broke down numerically.
+
+    The only exception the solver escalation ladder treats as a failed
+    attempt.  Anything else raised inside a solve — a compile, lowering,
+    runtime or out-of-memory error from JAX or Mosaic — is a fault of the
+    program or the device, not of the numbers, and reaches the caller.
+    """
+
+    def __init__(self, message: str, *, details: dict | None = None):
+        super().__init__("solver-breakdown", message, details=details)
+
+
+class RefineRejected(GuardError):
+    """A refinement result that failed its stage's own checks (invalid
+    labels, or a cut above the stage's input).  The stage degrades to the
+    host FM refiner on this error and on no other."""
+
+    def __init__(self, message: str, *, details: dict | None = None):
+        super().__init__("refine-rejected", message, details=details)
 
 
 @dataclasses.dataclass

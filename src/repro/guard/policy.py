@@ -16,7 +16,10 @@ escalates deterministically:
 
 The guard carries a per-stage attempt budget and an optional wall-clock
 deadline; once the deadline expires every remaining solve goes straight
-to the fallback rung.  :func:`enforce_output` is the pipeline's graceful
+to the fallback rung.  Only the health checks below and a raised
+:class:`~repro.guard.errors.SolverBreakdown` count as failed attempts: a
+JAX, XLA or Mosaic exception from a solve propagates to the caller.
+:func:`enforce_output` is the pipeline's graceful
 degradation closer: it guarantees valid labels, connected parts, and the
 weight corridor even when every spectral attempt failed.
 """
@@ -30,7 +33,7 @@ import numpy as np
 
 from repro import obs
 from repro.guard import chaos
-from repro.guard.errors import GuardReport
+from repro.guard.errors import GuardReport, SolverBreakdown
 from repro.mesh.graphs import connected_labels
 
 #: A residual this many times |λ| is garbage, not "slow convergence".
@@ -141,8 +144,9 @@ class SolverGuard:
     def rescue(self, solve_fn, first_why: str, *, level: int, p_lo: int,
                size: int, coords=None):
         """Run the ladder for one failed solve.  ``solve_fn(method, seed)``
-        re-solves the node's problem; exceptions count as failures.
-        Always returns a usable FiedlerResult."""
+        re-solves the node's problem; a :class:`SolverBreakdown` it raises
+        counts as a failed attempt, any other exception propagates.
+        Returns a usable FiedlerResult."""
         from repro.core.fiedler import FiedlerResult
 
         why = first_why
@@ -193,7 +197,7 @@ class SolverGuard:
         try:
             return solve_fn(method,
                             _node_seed(self.seed, level, p_lo, attempt))
-        except Exception:
+        except SolverBreakdown:
             return None
 
 

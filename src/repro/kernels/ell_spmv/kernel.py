@@ -4,20 +4,28 @@ TPU adaptation of the paper's Laplacian hot loop (DESIGN.md §2): instead of
 CSR rows (GPU-style one-thread-per-row), the adjacency is stored
 column-major ELL — `cols_t/vals_t : (w, n)` — so the *node* axis lands on
 the 128-wide vector lanes and each of the `w` neighbor slots is one fully
-vectorized multiply-gather-accumulate sweep.  The dense vector `x` stays
-resident in VMEM (the kernel targets AMG coarse levels and per-shard
-subgraphs, n ≤ ~256k: 1 MB of fp32 — comfortably inside the 16 MB VMEM of
-a v5e core); rows are streamed block-by-block.
+vectorized multiply-accumulate sweep down the sublanes.
 
-Grid: n / block_n column blocks.  Block shapes: (w, block_n) for cols/vals,
-(block_n,) for the output; x is broadcast (un-blocked) into VMEM once.
-block_n is a multiple of 128 (lane width); w is the padded max degree.
+The neighbor gather ``x[cols_t]`` runs in XLA before the ``pallas_call``
+(Mosaic lowers only 2-D gathers, and a gather of a resident vector by a
+``(w, block_n)`` index block is not one), so the kernel streams two
+``(w, block_n)`` blocks — gathered values and weights — and reduces them
+over the slot axis.  Nothing is resident: scoped VMEM per grid step is
+2 inputs × 2 pipeline buffers × w × block_n × 4 B (512 KiB at w = 32,
+block_n = 1024) whatever n is, so n is bounded by HBM, not VMEM: the XLA
+gather materialises w × n values there (32 MiB at n = 262,144, w = 32).
+Compiled for a TPU v5e at w = 32 for n = 262,144 (the level-0 packed
+operator of a 64³ box mesh; ``tests/test_tpu_compile.py``) and for
+n = 4,194,304 (1 GiB gathered).
+
+Grid: n / block_n column blocks; block_n is a multiple of 128 (lane width)
+and w, the padded max degree, is the full sublane extent of each block.
 
 **Batched variant** (`ell_spmv_batched_pallas`): B independent operators —
 the level-synchronous RSB engine's leading-batch-dim layout and the packed
-`BatchedAMG` level operators — add a leading batch grid dimension.  Each
-(b, i) grid step loads problem b's resident vector plus one (w, block_n)
-column block and writes one (block_n,) output block; column ids stay
+`BatchedAMG` level operators — add a leading batch grid dimension whose
+block dim is squeezed (``None``), so the last two block dims of every
+operand still follow the (8, 128) tiling rule.  Column ids stay
 per-problem (no cross-batch offsets), matching the jnp fallback in
 `EllLaplacian.adj_apply`.
 """
@@ -31,14 +39,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _spmv_kernel(x_ref, cols_ref, vals_ref, out_ref):
-    x = x_ref[...]                     # (n,) resident vector
-    cols = cols_ref[...]               # (w, bn)
-    vals = vals_ref[...]               # (w, bn)
-    gathered = jnp.take(x, cols, axis=0)          # (w, bn) vectorized gather
-    out_ref[...] = (vals.astype(jnp.float32) * gathered.astype(jnp.float32)).sum(
-        axis=0
-    ).astype(out_ref.dtype)
+def _spmv_kernel(xg_ref, vals_ref, out_ref):
+    xg = xg_ref[...].astype(jnp.float32)           # (w, bn) gathered x
+    vals = vals_ref[...].astype(jnp.float32)       # (w, bn)
+    out_ref[...] = (vals * xg).sum(axis=0, keepdims=True).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -52,29 +56,19 @@ def ell_spmv_pallas(
 ) -> jax.Array:
     w, n = cols_t.shape
     assert n % block_n == 0, (n, block_n)
-    grid = (n // block_n,)
-    return pl.pallas_call(
+    xg = jnp.take(x, cols_t, axis=0)               # (w, n), XLA gather
+    out = pl.pallas_call(
         _spmv_kernel,
-        grid=grid,
+        grid=(n // block_n,),
         in_specs=[
-            pl.BlockSpec((n,), lambda i: (0,)),            # x: whole vector
-            pl.BlockSpec((w, block_n), lambda i: (0, i)),  # cols block
+            pl.BlockSpec((w, block_n), lambda i: (0, i)),  # gathered x
             pl.BlockSpec((w, block_n), lambda i: (0, i)),  # vals block
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), x.dtype),
+        out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), x.dtype),
         interpret=interpret,
-    )(x, cols_t, vals_t)
-
-
-def _spmv_batched_kernel(x_ref, cols_ref, vals_ref, out_ref):
-    x = x_ref[0]                       # (n,) problem b's resident vector
-    cols = cols_ref[0]                 # (w, bn)
-    vals = vals_ref[0]                 # (w, bn)
-    gathered = jnp.take(x, cols, axis=0)          # (w, bn) vectorized gather
-    out_ref[0, :] = (vals.astype(jnp.float32) * gathered.astype(jnp.float32)).sum(
-        axis=0
-    ).astype(out_ref.dtype)
+    )(xg, vals_t)
+    return out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -88,16 +82,17 @@ def ell_spmv_batched_pallas(
 ) -> jax.Array:
     B, w, n = cols_t.shape
     assert n % block_n == 0, (n, block_n)
-    grid = (B, n // block_n)
-    return pl.pallas_call(
-        _spmv_batched_kernel,
-        grid=grid,
+    xg = jnp.take_along_axis(
+        x, cols_t.reshape(B, -1), axis=-1).reshape(B, w, n)
+    out = pl.pallas_call(
+        _spmv_kernel,
+        grid=(B, n // block_n),
         in_specs=[
-            pl.BlockSpec((1, n), lambda b, i: (b, 0)),            # x row b
-            pl.BlockSpec((1, w, block_n), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((1, w, block_n), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((None, w, block_n), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((None, w, block_n), lambda b, i: (b, 0, i)),
         ],
-        out_specs=pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((B, n), x.dtype),
+        out_specs=pl.BlockSpec((None, 1, block_n), lambda b, i: (b, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, n), x.dtype),
         interpret=interpret,
-    )(x, cols_t, vals_t)
+    )(xg, vals_t)
+    return out[:, 0]

@@ -10,12 +10,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.ell_spmv.kernel import ell_spmv_batched_pallas, ell_spmv_pallas
 from repro.kernels.ell_spmv.ref import ell_spmv_batched_ref, ell_spmv_ref
 
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pick_block(n: int) -> int:
@@ -31,7 +29,7 @@ def ell_spmv(cols: jax.Array, vals: jax.Array, x: jax.Array, *,
     dispatches per ``prefer``: "auto" (Pallas on TPU, jnp reference
     elsewhere) | "pallas" (interpret mode off-TPU) | "ref", padding n to
     a lane-aligned block size on the Pallas path."""
-    if prefer == "ref" or (prefer == "auto" and not _on_tpu()):
+    if prefer == "ref" or (prefer == "auto" and not on_tpu()):
         return ell_spmv_ref(cols.T, vals.T, x)
     n, w = cols.shape
     block = _pick_block(n)
@@ -41,10 +39,10 @@ def ell_spmv(cols: jax.Array, vals: jax.Array, x: jax.Array, *,
         vals = jnp.pad(vals, ((0, n_pad - n), (0, 0)))
         xp = jnp.pad(x, (0, n_pad - n))
         out = ell_spmv_pallas(
-            cols.T, vals.T, xp, block_n=128, interpret=not _on_tpu()
+            cols.T, vals.T, xp, block_n=128, interpret=not on_tpu()
         )
         return out[:n]
-    return ell_spmv_pallas(cols.T, vals.T, x, block_n=block, interpret=not _on_tpu())
+    return ell_spmv_pallas(cols.T, vals.T, x, block_n=block, interpret=not on_tpu())
 
 
 def ell_spmv_batched(cols: jax.Array, vals: jax.Array, x: jax.Array, *,
@@ -53,7 +51,7 @@ def ell_spmv_batched(cols: jax.Array, vals: jax.Array, x: jax.Array, *,
     per-problem vectors (B, n) — transposes to (B, w, n) ELLPACK-T and
     dispatches per ``prefer`` (see :func:`ell_spmv`), padding n to a
     lane-aligned block size on the Pallas path."""
-    if prefer == "ref" or (prefer == "auto" and not _on_tpu()):
+    if prefer == "ref" or (prefer == "auto" and not on_tpu()):
         return ell_spmv_batched_ref(cols.swapaxes(-1, -2),
                                     vals.swapaxes(-1, -2), x)
     B, n, w = cols.shape
@@ -65,12 +63,12 @@ def ell_spmv_batched(cols: jax.Array, vals: jax.Array, x: jax.Array, *,
         xp = jnp.pad(x, ((0, 0), (0, n_pad - n)))
         out = ell_spmv_batched_pallas(
             cols.swapaxes(-1, -2), vals.swapaxes(-1, -2), xp,
-            block_n=128, interpret=not _on_tpu(),
+            block_n=128, interpret=not on_tpu(),
         )
         return out[:, :n]
     return ell_spmv_batched_pallas(
         cols.swapaxes(-1, -2), vals.swapaxes(-1, -2), x,
-        block_n=block, interpret=not _on_tpu(),
+        block_n=block, interpret=not on_tpu(),
     )
 
 

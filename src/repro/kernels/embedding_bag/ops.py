@@ -9,12 +9,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.embedding_bag.kernel import embedding_bag_pallas
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
 
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def embedding_bag(
@@ -34,13 +32,13 @@ def embedding_bag(
     if not assume_sorted:
         order = jnp.argsort(segments)
         indices, segments, weights = indices[order], segments[order], weights[order]
-    if prefer == "ref" or (prefer == "auto" and not _on_tpu()):
+    if prefer == "ref" or (prefer == "auto" and not on_tpu()):
         return embedding_bag_ref(table, indices, segments, n_bags,
                                  weights=weights)
     d_pad = -(-d // 128) * 128
     tbl = jnp.pad(table, ((0, 0), (0, d_pad - d))) if d_pad != d else table
     out = embedding_bag_pallas(
         tbl, indices.astype(jnp.int32), segments.astype(jnp.int32), weights,
-        n_bags=n_bags, interpret=not _on_tpu(),
+        n_bags=n_bags, interpret=not on_tpu(),
     )
     return out[:, :d]

@@ -9,12 +9,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_ref
 
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def flash_attention(
@@ -28,7 +26,7 @@ def flash_attention(
     prefer: str = "auto",
 ) -> jax.Array:
     """Causal GQA attention, queries end-aligned with keys (ref.py semantics)."""
-    if prefer == "ref" or (prefer == "auto" and not _on_tpu()):
+    if prefer == "ref" or (prefer == "auto" and not on_tpu()):
         return attention_ref(q, k, v, causal=causal)
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
@@ -43,6 +41,6 @@ def flash_attention(
         v = jnp.pad(v, ((0, 0), (0, sk_pad - Skv), (0, 0), (0, 0)))
     out = flash_attention_pallas(
         q, k, v, causal=causal, q_offset=Skv - Sq, kv_len=Skv,
-        block_q=bq, block_k=bk, interpret=not _on_tpu(),
+        block_q=bq, block_k=bk, interpret=not on_tpu(),
     )
     return out[:, :Sq]

@@ -9,16 +9,21 @@ segment ids gathered through the ELL adjacency.
 Layout differs from `ell_spmv` deliberately: there the *node* axis rides
 the 128 lanes (output is a vector); here the output is a table whose lane
 axis is ``nparts`` (padded to 128), so ELL rows stay row-major —
-``cols/wts : (B, w)`` blocked as ``(block_b, w)`` on the sublane axis —
-and each of the ``w`` neighbor slots is one vectorized
-gather-compare-accumulate sweep into the resident ``(block_b, npad)``
-accumulator.  The combined label vector (n_local + P·halo ≤ ~256k int32 =
-1 MB) stays resident in VMEM, exactly like `ell_spmv`'s dense vector.
+``lab/wts : (B, w)`` blocked as ``(block_b, w)`` on the sublane axis —
+and each of the ``w`` neighbor slots is one vectorized compare-accumulate
+sweep into the ``(block_b, npad)`` accumulator.
+
+The label gather ``labels[cols]`` runs in XLA before the ``pallas_call``
+(Mosaic lowers only 2-D gathers), so the kernel receives the gathered
+``(B, w)`` int32 segment ids and nothing stays resident: scoped VMEM per
+grid step is two ``(block_b, w)`` input blocks and one
+``(block_b, npad)`` f32 output block, double-buffered, independent of the
+label-space size.
 
 Grid: B / block_b row blocks; the **batched variant** adds a leading
-shard-group dimension — one launch computes every shard's frontier table,
-which is what makes the refinement sweep a single kernel launch between
-collectives.
+shard-group dimension with a squeezed (``None``) block dim — one launch
+computes every shard's frontier table, which is what makes the
+refinement sweep a single kernel launch between collectives.
 """
 
 from __future__ import annotations
@@ -30,17 +35,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _segsum_kernel(labels_ref, cols_ref, wts_ref, out_ref):
-    labels = labels_ref[...]                     # (m,) resident labels
-    cols = cols_ref[...]                         # (bn, w)
+def _segsum_kernel(lab_ref, wts_ref, out_ref):
+    lab = lab_ref[...]                           # (bn, w) gathered seg ids
     wts = wts_ref[...].astype(jnp.float32)       # (bn, w)
-    lab = jnp.take(labels, cols, axis=0)         # (bn, w) gathered seg ids
     bn, npad = out_ref.shape
     iota = jax.lax.broadcasted_iota(lab.dtype, (1, npad), 1)
     acc = jnp.zeros((bn, npad), jnp.float32)
-    for k in range(cols.shape[1]):               # w is small and static
-        onehot = (lab[:, k][:, None] == iota).astype(jnp.float32)
-        acc = acc + wts[:, k][:, None] * onehot
+    for k in range(lab.shape[1]):                # w is small and static
+        onehot = (lab[:, k:k + 1] == iota).astype(jnp.float32)
+        acc = acc + wts[:, k:k + 1] * onehot
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
@@ -56,35 +59,19 @@ def segment_sum_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     B, w = cols.shape
-    m = labels.shape[0]
     assert B % block_b == 0, (B, block_b)
-    grid = (B // block_b,)
+    lab = jnp.take(labels, cols, axis=0)         # (B, w), XLA gather
     return pl.pallas_call(
         _segsum_kernel,
-        grid=grid,
+        grid=(B // block_b,),
         in_specs=[
-            pl.BlockSpec((m,), lambda i: (0,)),            # labels: resident
-            pl.BlockSpec((block_b, w), lambda i: (i, 0)),  # cols row block
-            pl.BlockSpec((block_b, w), lambda i: (i, 0)),  # wts row block
+            pl.BlockSpec((block_b, w), lambda i: (i, 0)),  # seg id rows
+            pl.BlockSpec((block_b, w), lambda i: (i, 0)),  # wts rows
         ],
         out_specs=pl.BlockSpec((block_b, nparts_pad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, nparts_pad), jnp.float32),
         interpret=interpret,
-    )(labels, cols, wts)
-
-
-def _segsum_batched_kernel(labels_ref, cols_ref, wts_ref, out_ref):
-    labels = labels_ref[0]                       # (m,) problem g's labels
-    cols = cols_ref[0]                           # (bn, w)
-    wts = wts_ref[0].astype(jnp.float32)         # (bn, w)
-    lab = jnp.take(labels, cols, axis=0)
-    _, bn, npad = out_ref.shape
-    iota = jax.lax.broadcasted_iota(lab.dtype, (1, npad), 1)
-    acc = jnp.zeros((bn, npad), jnp.float32)
-    for k in range(cols.shape[1]):
-        onehot = (lab[:, k][:, None] == iota).astype(jnp.float32)
-        acc = acc + wts[:, k][:, None] * onehot
-    out_ref[0, :, :] = acc.astype(out_ref.dtype)
+    )(lab, wts)
 
 
 @functools.partial(jax.jit,
@@ -99,19 +86,18 @@ def segment_sum_batched_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     G, B, w = cols.shape
-    m = labels.shape[1]
     assert B % block_b == 0, (B, block_b)
-    grid = (G, B // block_b)
+    lab = jnp.take_along_axis(
+        labels, cols.reshape(G, -1), axis=1).reshape(G, B, w)
     return pl.pallas_call(
-        _segsum_batched_kernel,
-        grid=grid,
+        _segsum_kernel,
+        grid=(G, B // block_b),
         in_specs=[
-            pl.BlockSpec((1, m), lambda g, i: (g, 0)),
-            pl.BlockSpec((1, block_b, w), lambda g, i: (g, i, 0)),
-            pl.BlockSpec((1, block_b, w), lambda g, i: (g, i, 0)),
+            pl.BlockSpec((None, block_b, w), lambda g, i: (g, i, 0)),
+            pl.BlockSpec((None, block_b, w), lambda g, i: (g, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_b, nparts_pad),
+        out_specs=pl.BlockSpec((None, block_b, nparts_pad),
                                lambda g, i: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((G, B, nparts_pad), jnp.float32),
         interpret=interpret,
-    )(labels, cols, wts)
+    )(lab, wts)

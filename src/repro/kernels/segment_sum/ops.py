@@ -20,6 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.segment_sum.kernel import (
     segment_sum_batched_pallas,
     segment_sum_pallas,
@@ -29,9 +30,6 @@ from repro.kernels.segment_sum.ref import (
     connection_table_ref,
 )
 
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pick_block_rows(b: int) -> int:
@@ -107,9 +105,9 @@ def connection_table(labels: jax.Array, cols: jax.Array, wts: jax.Array,
     B, w = cols.shape
     if B == 0 or w == 0:
         return jnp.zeros((B, nparts), jnp.float32)
-    if prefer == "pallas" or (prefer == "auto" and _on_tpu()):
+    if prefer == "pallas" or (prefer == "auto" and on_tpu()):
         return _pallas_padded(labels, cols, wts, nparts=nparts,
-                              interpret=not _on_tpu())
+                              interpret=not on_tpu())
     if prefer == "ref":
         return _ref_jit(labels, cols, wts, nparts=nparts)
     return _xla_loop(labels, cols, wts, nparts=nparts)
@@ -124,9 +122,9 @@ def connection_table_batched(labels: jax.Array, cols: jax.Array,
     G, B, w = cols.shape
     if B == 0 or w == 0:
         return jnp.zeros((G, B, nparts), jnp.float32)
-    if prefer == "pallas" or (prefer == "auto" and _on_tpu()):
+    if prefer == "pallas" or (prefer == "auto" and on_tpu()):
         return _pallas_batched_padded(labels, cols, wts, nparts=nparts,
-                                      interpret=not _on_tpu())
+                                      interpret=not on_tpu())
     if prefer == "ref":
         return _ref_batched_jit(labels, cols, wts, nparts=nparts)
     return _xla_loop_batched(labels, cols, wts, nparts=nparts)

@@ -24,6 +24,7 @@ from repro.guard import (
     GuardError,
     GuardPolicy,
     GuardReport,
+    SolverBreakdown,
     SolverGuard,
     chaos,
     check_output,
@@ -275,7 +276,7 @@ def _ladder(policy, script, method="lanczos", seed=0):
         i = len(calls) - 1
         if i in script:
             return script[i]
-        raise RuntimeError("scripted failure")
+        raise SolverBreakdown("scripted failure")
 
     sg = SolverGuard(policy, seed=seed, method=method)
     res, why = sg.admit(_res(np.zeros(16)), level=0, p_lo=0, size=16)
@@ -315,6 +316,35 @@ def test_ladder_no_switch_policy():
         GuardPolicy(max_retries=1, switch_method=False), {})
     assert out.method == "fallback-index"
     assert [m for m, _ in calls] == ["lanczos"]
+
+
+def test_ladder_lets_non_breakdown_errors_escape():
+    """Only a SolverBreakdown is a failed attempt: a compile, runtime or
+    out-of-memory error from a solve reaches the caller."""
+    sg = SolverGuard(GuardPolicy(max_retries=2), seed=0, method="lanczos")
+
+    def solve_fn(m, s):
+        raise RuntimeError("device failure")
+
+    with pytest.raises(RuntimeError, match="device failure"):
+        sg.rescue(solve_fn, "breakdown", level=0, p_lo=0, size=16)
+    assert sg.report.fallbacks == 0
+
+
+@pytest.mark.parametrize("engine", ["recursive", "batched"])
+def test_engine_solve_errors_reach_the_caller(engine, monkeypatch):
+    """A device error in the first solve of a guarded RSB run propagates
+    instead of degrading to the fallback vector."""
+    import repro.core.rsb as rsb
+
+    def boom(*a, **k):
+        raise RuntimeError("device failure")
+
+    monkeypatch.setattr(rsb, "fiedler_from_graph", boom)
+    monkeypatch.setattr(rsb, "fiedler_from_graph_batched", boom)
+    with pytest.raises(RuntimeError, match="device failure"):
+        rsb.rsb_partition_graph(grid_graph_2d(8, 8), 4, engine=engine,
+                                guard=GuardPolicy())
 
 
 def test_deadline_skips_straight_to_fallback():

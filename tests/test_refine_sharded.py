@@ -5,7 +5,8 @@ mirror on seeded meshes (integer weights ⇒ f32 sums are exact ⇒ identical
 labels), cut monotone per sweep, balance corridor held on globally reduced
 part weights, zero disconnected parts after the closing repair, sharded
 cut within 1% of the host FM refiner, exactly one boundary-label
-all_gather per sweep (trace counters), and the guard fallback path.  The
+all_gather per sweep (trace counters), the guard fallback on a
+rejected result, and propagation of device-path errors.  The
 8-device behaviour runs in a subprocess via the ``multi_device_run``
 conftest fixture (the main test process keeps 1 device).
 """
@@ -182,24 +183,53 @@ def test_guard_deadline_falls_back_to_host():
     assert partition_metrics(g, out, 8, weights=w).disconnected_parts == 0
 
 
-def test_device_path_failure_counts_guard_fallback():
-    """A broken device path trips the guard escalation counter and still
-    returns a host-refined partition."""
+def _rejected_result_falls_back(monkeypatch, out_of):
+    """Inject a sweep result ``out_of(parts)`` the stage must reject and
+    check it degrades to the host FM refiner, counted in guard_fallbacks."""
     import repro.dist.refine_sharded as rs
 
     g, parts, w, _ = _seeded_case(box_mesh(6, 6, 4), 4, 19)
-    orig = rs.run_sharded_sweeps
-    rs.run_sharded_sweeps = lambda *a, **k: (_ for _ in ()).throw(
-        RuntimeError("injected device failure"))
-    try:
-        with obs.trace("t") as root:
-            out, stats = refine_sharded_stage(g, parts, 4, weights=w)
-    finally:
-        rs.run_sharded_sweeps = orig
+    monkeypatch.setattr(rs, "run_sharded_sweeps", lambda fp, p, *a, **k: (
+        out_of(g, p), [], {"moves": 0, "gathers": 0, "cut": 0.0}))
+    with obs.trace("t") as root:
+        out, stats = refine_sharded_stage(g, parts, 4, weights=w)
     total = sum(s.counters.get("guard_fallbacks", 0) for s in root.walk())
     assert total >= 1
     assert "host-fallback" in stats.stages
     assert edge_cut(g, out) <= stats.cut_before + 1e-9
+
+
+def test_device_path_failure_counts_guard_fallback(monkeypatch):
+    """Invalid labels from the sweeps trip the guard escalation counter
+    and still return a host-refined partition."""
+    _rejected_result_falls_back(monkeypatch,
+                                lambda g, p: np.full_like(p, 99))
+
+
+def test_higher_cut_result_falls_back_to_host(monkeypatch):
+    """A sweep result with a higher cut than the stage's input is rejected
+    the same way (RefineRejected → host FM refiner)."""
+    def worse(g, p):
+        out = np.random.default_rng(0).integers(0, 4, g.n)
+        assert edge_cut(g, out) > edge_cut(g, p)
+        return out
+
+    _rejected_result_falls_back(monkeypatch, worse)
+
+
+def test_device_path_error_propagates(monkeypatch):
+    """An exception from the device path itself (compile, lowering,
+    runtime, out of memory) is a fault, not a degraded result: it reaches
+    the caller and no host fallback hides it."""
+    def boom(*a, **k):
+        raise RuntimeError("injected device failure")
+
+    import repro.dist.refine_sharded as rs
+
+    g, parts, w, _ = _seeded_case(box_mesh(6, 6, 4), 4, 19)
+    monkeypatch.setattr(rs, "run_sharded_sweeps", boom)
+    with pytest.raises(RuntimeError, match="injected device failure"):
+        refine_sharded_stage(g, parts, 4, weights=w)
 
 
 def test_eight_device_parity(multi_device_run):
