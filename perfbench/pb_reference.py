@@ -1,0 +1,282 @@
+"""Plain reference for the partition cells: dual graph, checker, λ₂.
+
+Nothing here imports the program under test.  The semantics follow the
+parRSB paper: two hex elements are adjacent when they share a vertex, and
+the edge weight ω is the number of vertices they share (1, 2 or 4).  A
+partition is sound when every label lies in ``[0, nparts)``, every part is
+non-empty and connected in the dual graph, and every part's weight lies
+inside ``(1 ± balance_tol)`` of the mean.  Its cut is the ω-weighted count
+of dual-graph edges whose ends lie in different parts.
+
+The Fiedler reference is λ₂ of the Laplacian ``L = D − A`` in float64
+(ARPACK through SciPy, or a dense solve when small), of the dual graph and
+of each subgraph that a node of the bisection tree induces.  :func:`lanczos_lambda2` is a plain
+windowed Lanczos in JAX that takes a dtype: at bfloat16 it is the control
+that the λ₂ comparison has to fail.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import hashlib
+
+import numpy as np
+import scipy.sparse as sp
+import scipy.sparse.csgraph as csgraph
+import scipy.sparse.linalg as sla
+
+
+@dataclasses.dataclass(frozen=True)
+class DualGraph:
+    """Symmetric CSR adjacency: both (i, j) and (j, i) are stored."""
+
+    adj: sp.csr_matrix     # (n, n) float64, ω on the off-diagonal
+
+    @property
+    def n(self) -> int:
+        return self.adj.shape[0]
+
+    @property
+    def nnz(self) -> int:
+        return int(self.adj.nnz)
+
+    def laplacian(self) -> sp.csr_matrix:
+        deg = np.asarray(self.adj.sum(axis=1)).ravel()
+        return (sp.diags(deg) - self.adj).tocsr()
+
+
+def dual_graph(vert_gid: np.ndarray) -> DualGraph:
+    """Vertex-sharing dual graph of an (E, 8) corner-id table."""
+    vert_gid = np.asarray(vert_gid)
+    E, K = vert_gid.shape
+    _, verts = np.unique(vert_gid.ravel(), return_inverse=True)
+    elems = np.repeat(np.arange(E), K)
+    inc = sp.csr_matrix((np.ones(E * K), (elems, verts.ravel())),
+                        shape=(E, int(verts.max()) + 1))
+    shared = (inc @ inc.T).tocsr()          # (E, E): shared-vertex counts
+    shared.setdiag(0)
+    shared.eliminate_zeros()
+    return DualGraph(adj=shared)
+
+
+def edge_cut(g: DualGraph, labels: np.ndarray) -> float:
+    """ω-weighted cut, each undirected edge counted once."""
+    coo = g.adj.tocoo()
+    cut = labels[coo.row] != labels[coo.col]
+    return float(coo.data[cut].sum() / 2.0)
+
+
+def check_partition(g: DualGraph, labels: np.ndarray, weights: np.ndarray,
+                    nparts: int, balance_tol: float) -> dict:
+    """Every plain check of one partition.  Counts are exact (limit 0);
+    ``balance`` is the largest relative distance of a part's weight from
+    the mean; ``cut`` is the ω-weighted cut."""
+    labels = np.asarray(labels)
+    out = {"out_of_range": int(((labels < 0) | (labels >= nparts)).sum()),
+           "empty_parts": nparts, "disconnected_parts": nparts,
+           "balance": float("inf"), "cut": float("nan")}
+    if labels.shape != (g.n,) or out["out_of_range"]:
+        out["out_of_range"] = max(out["out_of_range"], 1)
+        return out
+    labels = labels.astype(np.int64)
+    pw = np.bincount(labels, weights=weights, minlength=nparts)
+    mean = float(weights.sum()) / nparts
+    out["empty_parts"] = int((np.bincount(labels, minlength=nparts) == 0).sum())
+    out["balance"] = float(np.abs(pw / mean - 1.0).max())
+    coo = g.adj.tocoo()
+    same = labels[coo.row] == labels[coo.col]
+    inside = sp.csr_matrix((np.ones(int(same.sum())),
+                            (coo.row[same], coo.col[same])), shape=g.adj.shape)
+    _, comp = csgraph.connected_components(inside, directed=False)
+    # A part with k > 1 components contributes k − 1 surplus components.
+    pairs = np.unique(np.stack([labels, comp], axis=1), axis=0)
+    per_part = np.bincount(pairs[:, 0], minlength=nparts)
+    out["disconnected_parts"] = int((per_part > 1).sum())
+    out["cut"] = float(coo.data[~same].sum() / 2.0)
+    return out
+
+
+DENSE_N = 600      # below this a dense solve is quicker than ARPACK
+
+
+def lambda2_reference(g: DualGraph) -> float:
+    """λ₂ of the dual-graph Laplacian in float64: the second smallest
+    eigenvalue, 0 where the graph is disconnected."""
+    if g.n <= DENSE_N:
+        return float(np.linalg.eigvalsh(g.laplacian().toarray())[1])
+    vals = sla.eigsh(g.laplacian(), k=3, which="SA", tol=1e-12, ncv=60,
+                     maxiter=100_000, return_eigenvectors=False)
+    vals = np.sort(vals)
+    return float(vals[1])
+
+
+def subgraph(g: DualGraph, idx: np.ndarray) -> DualGraph:
+    """The subgraph that the elements ``idx`` induce."""
+    return DualGraph(adj=g.adj[idx][:, idx].tocsr())
+
+
+class NodeLambda2:
+    """λ₂ of the subgraphs of one graph, each solved once and kept by its
+    element set."""
+
+    def __init__(self, g: DualGraph):
+        self.g, self._seen = g, {}
+
+    def __call__(self, idx: np.ndarray) -> tuple[float, float]:
+        """``(λ₂, scale)`` of the subgraph ``idx`` induces.  The scale is
+        λ₂ itself where the subgraph is connected; where it is not, λ₂ is
+        0, and the scale is the subgraph's mean weighted degree."""
+        key = hashlib.blake2b(np.sort(idx).tobytes(), digest_size=16).digest()
+        if key not in self._seen:
+            sub = subgraph(self.g, idx)
+            ncomp, _ = csgraph.connected_components(sub.adj, directed=False)
+            if ncomp == 1:
+                lam = lambda2_reference(sub)
+                self._seen[key] = (lam, lam)
+            else:
+                self._seen[key] = (0.0, float(sub.adj.sum()) / sub.n)
+        return self._seen[key]
+
+    def rel_err(self, value: float, idx: np.ndarray) -> float:
+        lam, scale = self(idx)
+        return abs(value - lam) / scale
+
+    def top(self) -> float:
+        return self(np.arange(self.g.n))[0]
+
+
+def tree_nodes(raw: np.ndarray, nparts: int) -> list:
+    """The nodes of the bisection tree that the raw labels ``raw`` (before
+    any post stage) imply, level by level and in part order:
+    ``(level, parts, elements)``.  A node holds the parts ``[lo, hi)``;
+    its halves hold ``[lo, lo + (hi − lo) // 2)`` and the rest.  A node of
+    one part or one element is a leaf and has no solve."""
+    raw = np.asarray(raw)
+    out, level = [], 0
+    active = [(0, nparts, np.arange(raw.size))]
+    while active:
+        nxt = []
+        for lo, hi, idx in active:
+            if hi - lo <= 1 or idx.size <= 1:
+                continue
+            out.append((level, hi - lo, idx))
+            mid = lo + (hi - lo) // 2
+            left = raw[idx] < mid
+            nxt += [(lo, mid, idx[left]), (mid, hi, idx[~left])]
+        active, level = nxt, level + 1
+    return out
+
+
+def rcb_labels(coords: np.ndarray, weights: np.ndarray,
+               nparts: int) -> np.ndarray:
+    """Plain recursive coordinate bisection: split the longest axis at the
+    weighted point that gives the halves ⌊p/2⌋ and ⌈p/2⌉ of the weight."""
+    labels = np.zeros(coords.shape[0], dtype=np.int64)
+
+    def rec(idx, lo, hi):
+        p = hi - lo
+        if p <= 1 or idx.size <= 1:
+            labels[idx] = lo
+            return
+        c = coords[idx]
+        ax = int(np.argmax(c.max(0) - c.min(0)))
+        order = idx[np.argsort(c[:, ax], kind="stable")]
+        cw = np.cumsum(weights[order])
+        k = int(np.searchsorted(cw, cw[-1] * (p // 2) / p)) + 1
+        k = min(max(k, 1), idx.size - 1)
+        rec(order[:k], lo, lo + p // 2)
+        rec(order[k:], lo + p // 2, hi)
+
+    rec(np.arange(coords.shape[0]), 0, nparts)
+    return labels
+
+
+def ell_arrays(g: DualGraph, n_pad: int = 0,
+               width_pad: int = 0) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(cols, vals, deg) of the Laplacian in padded ELL form, ``n_pad``
+    rows and ``width_pad`` columns at least; padding entries point at
+    their own row with weight 0, and padding rows have degree 0."""
+    adj = g.adj
+    deg_count = np.diff(adj.indptr)
+    width = max(int(deg_count.max()), 1, width_pad)
+    n = max(g.n, n_pad)
+    rows = np.repeat(np.arange(g.n), deg_count)
+    pos = np.arange(adj.nnz) - adj.indptr[rows]
+    cols = np.tile(np.arange(n)[:, None], (1, width))
+    vals = np.zeros((n, width))
+    deg = np.zeros(n)
+    cols[rows, pos] = adj.indices
+    vals[rows, pos] = adj.data
+    deg[:g.n] = np.asarray(adj.sum(axis=1)).ravel()
+    return cols, vals, deg
+
+
+def _pow2(n: int) -> int:
+    return 1 << max(int(n) - 1, 1).bit_length()
+
+
+@functools.lru_cache(maxsize=None)
+def _lanczos_program(steps: int):
+    """The jitted window of :func:`lanczos_lambda2`: (α, β) of ``steps``
+    Lanczos steps in the dtype of its inputs.  Rows where ``mask`` is 0
+    are padding: they start at 0 and stay there."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def run(cols, vals, deg, mask, q0):
+        dtype = q0.dtype
+        n_real = jnp.sum(mask)
+
+        def op(x):
+            return deg * x - (vals * x[cols]).sum(-1)
+
+        def step(j, carry):
+            Q, q, q_prev, b_prev, alpha, beta = carry
+            w = op(q) - b_prev * q_prev
+            a = jnp.vdot(w, q)
+            w = w - a * q
+            Q = Q.at[j].set(q)
+            for _ in range(2):
+                w = w - Q.T @ (Q @ w)
+                w = w - mask * (jnp.sum(w) / n_real)
+            b = jnp.sqrt(jnp.vdot(w, w))
+            q_next = w / jnp.maximum(b, jnp.asarray(1e-30, dtype))
+            return (Q, q_next, q, b, alpha.at[j].set(a), beta.at[j].set(b))
+
+        n = q0.shape[0]
+        z = jnp.zeros((), dtype)
+        init = (jnp.zeros((steps, n), dtype), q0, jnp.zeros_like(q0), z,
+                jnp.zeros((steps,), dtype), jnp.zeros((steps,), dtype))
+        _, _, _, _, alpha, beta = jax.lax.fori_loop(0, steps, step, init)
+        return alpha, beta
+
+    return run
+
+
+def lanczos_lambda2(g: DualGraph, *, dtype, steps: int, seed: int) -> float:
+    """λ₂ by plain Lanczos (full reorthogonalisation, constants deflated)
+    with every vector and the operator held in ``dtype`` on the default
+    JAX device: the smallest Ritz value of the window.  The graph is
+    padded to a power of two of rows, and the window is cut to half of
+    that, so that it never outruns the graph and few shapes compile."""
+    import jax.numpy as jnp
+
+    n_pad = _pow2(max(g.n, 8))
+    steps = min(steps, n_pad // 2)
+    cols, vals, deg = ell_arrays(g, n_pad, _pow2(np.diff(g.adj.indptr).max()))
+    mask = np.zeros(n_pad)
+    mask[:g.n] = 1.0
+    q0 = np.zeros(n_pad)
+    q0[:g.n] = np.random.default_rng(seed % 2**64).standard_normal(g.n)
+    q0[:g.n] -= q0[:g.n].mean()
+    q0 /= np.linalg.norm(q0)
+    alpha, beta = _lanczos_program(steps)(
+        jnp.asarray(cols, jnp.int32), jnp.asarray(vals, dtype),
+        jnp.asarray(deg, dtype), jnp.asarray(mask, dtype),
+        jnp.asarray(q0, dtype))
+    a = np.asarray(alpha.astype(jnp.float32), np.float64)
+    b = np.asarray(beta.astype(jnp.float32), np.float64)
+    T = np.diag(a) + np.diag(b[:-1], 1) + np.diag(b[:-1], -1)
+    return float(np.linalg.eigvalsh(T)[0])
