@@ -42,7 +42,6 @@ from repro.core.laplacian import (
     fill_ell_block as _fill_ell_block,
 )
 from repro.mesh.graphs import Graph, dual_graph_from_incidence
-from repro.obs import jaxprof
 
 _DENSE_CUTOFF = 192
 
@@ -344,11 +343,10 @@ def fiedler_from_graph(
     if method == "lanczos":
         # Pass the operator dataclass itself (a pytree): the window trace
         # is shared across same-shape operators instead of per instance.
-        with jaxprof.annotate("fiedler:lanczos"):
-            y, info = lanczos_fiedler(
-                op, n_pad, mask=mask, key=jax.random.PRNGKey(seed), b0=b0,
-                window=window, max_restarts=max_restarts, tol=tol,
-            )
+        y, info = lanczos_fiedler(
+            op, n_pad, mask=mask, key=jax.random.PRNGKey(seed), b0=b0,
+            window=window, max_restarts=max_restarts, tol=tol,
+        )
         iters = info.restarts
         lam, res = info.eigenvalue, info.residual
         broke = info.breakdown
@@ -362,11 +360,10 @@ def fiedler_from_graph(
             u = pre(r[:n])
             return jnp.pad(u, (0, n_pad - n))
 
-        with jaxprof.annotate("fiedler:inverse"):
-            y, info = inverse_iteration(
-                op.apply, n_pad, precond=precond, mask=mask,
-                key=jax.random.PRNGKey(seed), b0=b0, tol=tol,
-            )
+        y, info = inverse_iteration(
+            op.apply, n_pad, precond=precond, mask=mask,
+            key=jax.random.PRNGKey(seed), b0=b0, tol=tol,
+        )
         iters = info.outer_iters
         lam, res = info.eigenvalue, info.residual
         broke = info.breakdown
@@ -428,11 +425,10 @@ def fiedler_from_mesh(
         b0 = jnp.asarray(_noise_b0(seed, n_pad))
 
     if method == "lanczos":
-        with jaxprof.annotate("fiedler:lanczos"):
-            y, info = lanczos_fiedler(
-                op, n_pad, mask=mask, key=jax.random.PRNGKey(seed), b0=b0,
-                window=window, max_restarts=max_restarts, tol=tol,
-            )
+        y, info = lanczos_fiedler(
+            op, n_pad, mask=mask, key=jax.random.PRNGKey(seed), b0=b0,
+            window=window, max_restarts=max_restarts, tol=tol,
+        )
         iters, lam, res = info.restarts, info.eigenvalue, info.residual
         broke = info.breakdown
     elif method == "inverse":
@@ -446,11 +442,10 @@ def fiedler_from_mesh(
             u = pre(r[:E])
             return jnp.pad(u, (0, n_pad - E))
 
-        with jaxprof.annotate("fiedler:inverse"):
-            y, info = inverse_iteration(
-                op.apply, n_pad, precond=precond, mask=mask,
-                key=jax.random.PRNGKey(seed), b0=b0, tol=tol,
-            )
+        y, info = inverse_iteration(
+            op.apply, n_pad, precond=precond, mask=mask,
+            key=jax.random.PRNGKey(seed), b0=b0, tol=tol,
+        )
         iters, lam, res = info.outer_iters, info.eigenvalue, info.residual
         broke = info.breakdown
         obs.counter_add("cg_inner_iters", float(np.sum(info.inner_iters)))
@@ -625,10 +620,9 @@ def _solve_inverse_buckets(results, solve_ix, size_of, bucket_key, build_op,
             [size_of(i) for i in ix], [seeds[i] for i in ix],
             [warms[i] for i in ix], n_pad, b_pad,
         )
-        with jaxprof.annotate(f"fiedler:inverse_batched:n{n_pad}xb{b_pad}"):
-            Y, info = inverse_iteration_batched(
-                op, n_pad, mask=jnp.asarray(mask), b0=b0, tol=tol, precond=pre
-            )
+        Y, info = inverse_iteration_batched(
+            op, n_pad, mask=jnp.asarray(mask), b0=b0, tol=tol, precond=pre
+        )
         obs.counter_add(
             "cg_inner_iters",
             float(sum(np.asarray(c).sum() for c in info.inner_iters)))
@@ -645,11 +639,10 @@ def _solve_inverse_buckets(results, solve_ix, size_of, bucket_key, build_op,
 
 def _solve_packed_lanczos(op, offs, N, n_seg, seg, mask, b0, sizes,
                           tol, window, max_restarts):
-    with jaxprof.annotate(f"fiedler:lanczos_packed:N{N}"):
-        Y, info = lanczos_fiedler_batched(
-            op, N, seg=jnp.asarray(seg), n_seg=n_seg, mask=jnp.asarray(mask),
-            b0=b0, window=window, max_restarts=max_restarts, tol=tol,
-        )
+    Y, info = lanczos_fiedler_batched(
+        op, N, seg=seg, n_seg=n_seg, mask=mask,
+        b0=b0, window=window, max_restarts=max_restarts, tol=tol,
+    )
     Yh = np.asarray(Y)
     return [
         FiedlerResult(
@@ -702,39 +695,45 @@ def fiedler_from_graph_batched(
     seeds, warms = _normalize_batch_args(B, seeds, warms)
     results: list = [None] * B
     solve_ix = []
-    for i, g in enumerate(graphs):
-        if g.n <= _DENSE_CUTOFF:
-            vec, lam = _dense_fiedler(dense_laplacian_np(g))
-            results[i] = FiedlerResult(vec, lam, 0.0, 0, "dense")
-        else:
-            solve_ix.append(i)
+    # The host's work ahead of the device: dense solves of the small
+    # problems and the multilevel warm starts of the others.
+    with obs.timed("warm_start"):
+        for i, g in enumerate(graphs):
+            if g.n <= _DENSE_CUTOFF:
+                vec, lam = _dense_fiedler(dense_laplacian_np(g))
+                results[i] = FiedlerResult(vec, lam, 0.0, 0, "dense")
+            else:
+                solve_ix.append(i)
+        ml_levels = {i: 0 for i in solve_ix}
+        if multilevel:
+            for i in solve_ix:
+                if warms[i] is None:
+                    warms[i], ml_levels[i] = multilevel_warm_start(graphs[i])
+                    if warms[i] is not None and method == "inverse":
+                        warms[i] = _blend_noise(warms[i], seeds[i])
     if not solve_ix:
         _emit_fiedler_metrics(results)
         return results
 
-    ml_levels = {i: 0 for i in solve_ix}
-    if multilevel:
-        for i in solve_ix:
-            if warms[i] is None:
-                warms[i], ml_levels[i] = multilevel_warm_start(graphs[i])
-                if warms[i] is not None and method == "inverse":
-                    warms[i] = _blend_noise(warms[i], seeds[i])
-
     if method == "lanczos":
-        sizes = [graphs[i].n for i in solve_ix]
-        offs, N, n_seg, seg, mask = _pack_layout(sizes, pack_slots, pack_segs)
-        width = max(
-            int(graphs[i].degrees.max()) if graphs[i].nnz else 1
-            for i in solve_ix
-        )
-        width = next_pow2(max(width, 2))
-        if width_pad is not None:
-            width = max(width, int(width_pad))
-        op = _packed_ell_laplacian([graphs[i] for i in solve_ix], offs, N, width)
-        if use_kernel:
-            op = dataclasses.replace(op, use_kernel=True)
-        b0 = _packed_b0(sizes, offs, N, [seeds[i] for i in solve_ix],
-                        [warms[i] for i in solve_ix])
+        with obs.timed("pack"):
+            sizes = [graphs[i].n for i in solve_ix]
+            offs, N, n_seg, seg, mask = _pack_layout(sizes, pack_slots,
+                                                     pack_segs)
+            width = max(
+                int(graphs[i].degrees.max()) if graphs[i].nnz else 1
+                for i in solve_ix
+            )
+            width = next_pow2(max(width, 2))
+            if width_pad is not None:
+                width = max(width, int(width_pad))
+            op = _packed_ell_laplacian([graphs[i] for i in solve_ix], offs,
+                                       N, width)
+            if use_kernel:
+                op = dataclasses.replace(op, use_kernel=True)
+            b0 = _packed_b0(sizes, offs, N, [seeds[i] for i in solve_ix],
+                            [warms[i] for i in solve_ix])
+            seg, mask = jnp.asarray(seg), jnp.asarray(mask)
         packed = _solve_packed_lanczos(
             op, offs, N, n_seg, seg, mask, b0, sizes, tol, window, max_restarts
         )
@@ -839,7 +838,8 @@ def fiedler_from_mesh_batched(
         b0 = _packed_b0(sizes, offs, N, [seeds[i] for i in solve_ix],
                         [warms[i] for i in solve_ix])
         packed = _solve_packed_lanczos(
-            op, offs, N, n_seg, seg, mask, b0, sizes, tol, window, max_restarts
+            op, offs, N, n_seg, jnp.asarray(seg), jnp.asarray(mask), b0,
+            sizes, tol, window, max_restarts
         )
         for r, i in enumerate(solve_ix):
             results[i] = packed[r]

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.flexcg import _project_out_ones
 
 
@@ -275,43 +276,48 @@ def lanczos_fiedler_batched(
     start-vector projection, per-problem freezing, and convergence
     bookkeeping are cheap O(N) passes, and keeping them off the device
     means the ONLY compiled code on this path is the restart step itself.
+    The obs span ``restarts`` covers all of it, and the counter
+    ``restart_launches`` counts the `_packed_restart` launches.
     """
-    seg_h = np.asarray(seg)
-    mask_h = np.asarray(mask, dtype=np.float64)
-    q_h = np.asarray(b0, dtype=np.float64)
-    # Host analogue of _project_out_ones_seg + per-segment normalization.
-    s = np.bincount(seg_h, weights=q_h * mask_h, minlength=n_seg)
-    c = np.maximum(np.bincount(seg_h, weights=mask_h, minlength=n_seg), 1.0)
-    q_h = (q_h - (s / c)[seg_h]) * mask_h
-    nrm = np.sqrt(np.bincount(seg_h, weights=q_h * q_h, minlength=n_seg))
-    q_h = q_h / np.maximum(nrm, 1e-30)[seg_h]
-    q = jnp.asarray(q_h.astype(np.float32))
+    with obs.timed("restarts"):
+        seg_h = np.asarray(seg)
+        mask_h = np.asarray(mask, dtype=np.float64)
+        q_h = np.asarray(b0, dtype=np.float64)
+        # Host analogue of _project_out_ones_seg + per-segment normalization.
+        s = np.bincount(seg_h, weights=q_h * mask_h, minlength=n_seg)
+        c = np.maximum(np.bincount(seg_h, weights=mask_h, minlength=n_seg), 1.0)
+        q_h = (q_h - (s / c)[seg_h]) * mask_h
+        nrm = np.sqrt(np.bincount(seg_h, weights=q_h * q_h, minlength=n_seg))
+        q_h = q_h / np.maximum(nrm, 1e-30)[seg_h]
+        q = jnp.asarray(q_h.astype(np.float32))
 
-    y = q_h.astype(np.float32)
-    theta = np.zeros(n_seg)
-    res = np.full(n_seg, np.inf)
-    done = np.zeros(n_seg, dtype=bool)
-    breakdown = np.zeros(n_seg, dtype=bool)
-    restarts = np.zeros(n_seg, dtype=np.int64)
-    for r in range(1, max_restarts + 1):
-        y_new, theta_new, res_new, q_next = _packed_restart(
-            op, q, mask, seg, n_seg, window
-        )
-        theta_h, res_h = np.asarray(theta_new), np.asarray(res_new)
-        finite = np.isfinite(theta_h) & np.isfinite(res_h)
-        upd = ~done & finite  # a non-finite restart keeps the last state
-        restarts[upd] = r
-        theta = np.where(upd, theta_h, theta)
-        res = np.where(upd, res_h, res)
-        y = np.where(upd[seg_h], np.asarray(y_new), y)
-        done |= res <= tol * np.maximum(theta, 1e-12)
-        # Numerical breakdown: freeze the problem and flag it — its frozen
-        # (θ, res) never met tolerance.
-        breakdown |= ~finite & ~done
-        done |= ~finite
-        if done.all():
-            break
-        q = q_next
+        y = q_h.astype(np.float32)
+        theta = np.zeros(n_seg)
+        res = np.full(n_seg, np.inf)
+        done = np.zeros(n_seg, dtype=bool)
+        breakdown = np.zeros(n_seg, dtype=bool)
+        restarts = np.zeros(n_seg, dtype=np.int64)
+        r = 0
+        for r in range(1, max_restarts + 1):
+            y_new, theta_new, res_new, q_next = _packed_restart(
+                op, q, mask, seg, n_seg, window
+            )
+            theta_h, res_h = np.asarray(theta_new), np.asarray(res_new)
+            finite = np.isfinite(theta_h) & np.isfinite(res_h)
+            upd = ~done & finite  # a non-finite restart keeps the last state
+            restarts[upd] = r
+            theta = np.where(upd, theta_h, theta)
+            res = np.where(upd, res_h, res)
+            y = np.where(upd[seg_h], np.asarray(y_new), y)
+            done |= res <= tol * np.maximum(theta, 1e-12)
+            # Numerical breakdown: freeze the problem and flag it — its frozen
+            # (θ, res) never met tolerance.
+            breakdown |= ~finite & ~done
+            done |= ~finite
+            if done.all():
+                break
+            q = q_next
+        obs.counter_add("restart_launches", r)
 
     info = BatchedLanczosInfo(
         restarts=restarts, eigenvalue=theta, residual=res, converged=done,

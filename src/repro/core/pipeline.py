@@ -520,6 +520,8 @@ class PartitionPipeline:
         ctx.config = {"pre": self.pre, "bisect": self.bisect,
                       "post": list(self.post), "nparts": nparts, "n": ctx.n,
                       "guard": guard_on}
+        if spectral:
+            ctx.config["method"] = self.bisect_kw.get("method", "lanczos")
 
         root = obs.trace("partition", nparts=nparts, n=ctx.n,
                          pre=self.pre, bisect=self.bisect,
@@ -564,25 +566,30 @@ class PartitionPipeline:
         mode, plus component detection (disconnected inputs are handled
         downstream, never rejected here)."""
         with obs.timed("guard:validate") as t:
-            validate_nparts(nparts, ctx.n)
-            if ctx.mesh is not None:
-                mesh = ctx.mesh
-                if (ctx.coords is not mesh.coords
-                        or ctx.weights is not mesh.weights):
-                    mesh = dataclasses.replace(
-                        mesh, coords=np.asarray(ctx.coords, np.float64),
-                        weights=np.asarray(ctx.weights, np.float64))
-                mesh = validate_mesh(mesh, nparts=nparts,
-                                     sanitize=policy.sanitize,
-                                     report=greport)
-                ctx.mesh = mesh
-                ctx.coords, ctx.weights = mesh.coords, mesh.weights
-            else:
-                g, c, w = validate_graph(
-                    ctx.graph, coords=ctx.coords, weights=ctx.weights,
-                    nparts=nparts, sanitize=policy.sanitize, report=greport)
-                ctx.graph, ctx.coords, ctx.weights = g, c, w
-            comp, ncomp = component_labels(ctx.require_graph())
+            with obs.timed("validate"):
+                validate_nparts(nparts, ctx.n)
+                if ctx.mesh is not None:
+                    mesh = ctx.mesh
+                    if (ctx.coords is not mesh.coords
+                            or ctx.weights is not mesh.weights):
+                        mesh = dataclasses.replace(
+                            mesh, coords=np.asarray(ctx.coords, np.float64),
+                            weights=np.asarray(ctx.weights, np.float64))
+                    mesh = validate_mesh(mesh, nparts=nparts,
+                                         sanitize=policy.sanitize,
+                                         report=greport)
+                    ctx.mesh = mesh
+                    ctx.coords, ctx.weights = mesh.coords, mesh.weights
+                else:
+                    g, c, w = validate_graph(
+                        ctx.graph, coords=ctx.coords, weights=ctx.weights,
+                        nparts=nparts, sanitize=policy.sanitize,
+                        report=greport)
+                    ctx.graph, ctx.coords, ctx.weights = g, c, w
+            with obs.timed("dual_graph"):
+                graph = ctx.require_graph()
+            with obs.timed("components"):
+                comp, ncomp = component_labels(graph)
             greport.components = max(greport.components, ncomp)
             if greport.sanitize_fixes:
                 obs.counter_add("guard_sanitize_fixes",

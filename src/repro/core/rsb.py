@@ -96,6 +96,8 @@ class LevelRecord:
     iterations: int          # Σ per-node restarts / outer iterations
     solve_seconds: float     # Fiedler solves (batched: the bucket solves)
     split_seconds: float     # sort/split + child extraction
+    window: int = 0          # Lanczos window of the level's packed solve
+                             # (0: no packed Lanczos solve at this level)
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -563,22 +565,32 @@ def _rsb_graph_batched(
 
         active = [(graph, np.arange(n, dtype=np.int64), 0, nparts)]
         level = 0
+        reorder = None
+        if pre in ("rcb", "rib") and coords is not None:
+            reorder = rcb_order if pre == "rcb" else rib_order
         while active:
             solve_nodes = []
-            for g, idx, p_lo, p_hi in active:
+            for node in active:
+                _, idx, p_lo, p_hi = node
                 if p_hi - p_lo <= 1 or idx.size <= 1:
                     parts[idx] = p_lo
-                    continue
-                if pre in ("rcb", "rib") and coords is not None:
-                    fn = rcb_order if pre == "rcb" else rib_order
-                    perm = fn(coords[idx], w[idx])
-                    idx = idx[perm]
-                    g = g.sub(perm)
-                solve_nodes.append((g, idx, p_lo, p_hi))
+                else:
+                    solve_nodes.append(node)
             if not solve_nodes:
                 break
 
             with obs.span(f"level:{level}", nodes=len(solve_nodes)):
+                if reorder is not None:
+                    # Two passes over the level's nodes, so the geometric
+                    # orders and the subgraph relabels are timed apart.
+                    with obs.timed("reorder", level=level):
+                        perms = [reorder(coords[idx], w[idx])
+                                 for _, idx, _, _ in solve_nodes]
+                    with obs.timed("sub", level=level):
+                        solve_nodes = [
+                            (g.sub(perm), idx[perm], p_lo, p_hi)
+                            for (g, idx, p_lo, p_hi), perm
+                            in zip(solve_nodes, perms)]
                 with obs.timed("solve", level=level) as t_solve:
                     if sg is not None and sg.expired():
                         # Past the stage deadline: skip the level solve and
@@ -645,6 +657,8 @@ def _rsb_graph_batched(
                 iterations=sum(r.iterations for r in results),
                 solve_seconds=t_solve.seconds,
                 split_seconds=t_split.seconds,
+                window=window if method == "lanczos" and any(
+                    r.method == "lanczos" for r in results) else 0,
             ))
             # Per-node split cost isn't separable in the level-synchronous
             # engine; attribute the level's split evenly so engine comparisons

@@ -1,4 +1,4 @@
-"""repro.obs — hierarchical tracing, solver metrics, and profiler hooks.
+"""repro.obs — hierarchical tracing, solver metrics, and exporters.
 
 See ``src/repro/obs/README.md`` for the API tour and exporter formats.
 """
@@ -15,7 +15,6 @@ from repro.obs.export import (
     write_manifest,
     write_trace_events,
 )
-from repro.obs.jaxprof import annotate, maybe_start_trace, maybe_stop_trace
 from repro.obs.registry import (
     MetricDef,
     lookup,
@@ -49,5 +48,4 @@ __all__ = [
     "SCHEMA", "expected_span_names", "git_sha", "load_manifest",
     "manifest_lines", "run_path", "to_trace_events", "validate_manifest",
     "write_manifest", "write_trace_events",
-    "annotate", "maybe_start_trace", "maybe_stop_trace",
 ]

@@ -183,10 +183,12 @@ def expected_span_names(config: dict) -> set:
     """Span names a partition trace MUST contain given its recorded
     pipeline config — the CI drift guard's contract.  Derived from the
     same fields ``PartitionPipeline.run`` stamps into the manifest."""
+    from repro.core.fiedler import _DENSE_CUTOFF   # obs loads before core
+
     names = {"partition"}
     if config.get("guard"):
-        names.add("guard:validate")
-        names.add("guard:finalize")
+        names.update({"guard:validate", "validate", "dual_graph",
+                      "components", "guard:finalize"})
     pre = config.get("pre")
     if pre and pre != "none":
         names.add(f"pre:{pre}")
@@ -200,6 +202,16 @@ def expected_span_names(config: dict) -> set:
         if bisect in ("rsb-batched", "rsb-recursive") and single_comp:
             names.add("solve")
             names.add("split")
+        if bisect == "rsb-batched" and single_comp:
+            # The level loop's geometric reorder and relabel, and the
+            # batched solve's host steps; a packed Lanczos solve runs once
+            # the root is above the dense cutoff.
+            names.add("warm_start")
+            if pre in ("rcb", "rib"):
+                names.update({"reorder", "sub"})
+            if (config.get("method", "lanczos") == "lanczos"
+                    and config.get("n", 0) > _DENSE_CUTOFF):
+                names.update({"pack", "restarts"})
         elif bisect == "multilevel" and single_comp:
             # The V-cycle emits mlevel:N per ladder level, but only
             # mlevel:0 is guaranteed by construction (the stage runs the

@@ -85,6 +85,9 @@ def merge_metrics(dst: dict, src: dict, *, kind: str = "counter") -> dict:
 # Fiedler / eigensolvers
 register("lanczos_restarts", "counter",
          description="Restarted-Lanczos restart count across solves")
+register("restart_launches", "counter",
+         description="Launches of the batched Lanczos restart program "
+                     "(_packed_restart), one count per launch and level")
 register("lanczos_iters", "counter",
          description="Total Lanczos iterations (all restarts)")
 register("inverse_outer_iters", "counter",
@@ -170,8 +173,12 @@ register("guard_deadline_expired", "counter",
 SPAN_NAMES = (
     # pipeline skeleton
     "partition", "guard:validate", "guard:finalize",
+    # inside guard:validate
+    "validate", "dual_graph", "components",
     # solver engines
     "engine", "solve", "split",
+    # batched engine level loop, and the batched solve's host steps
+    "reorder", "sub", "warm_start", "pack", "restarts",
     # multilevel V-cycle
     "coarsen", "coarsest", "finalize",
     # host post chain

@@ -32,10 +32,16 @@ through every layer to be visible; subtree aggregation
 (:meth:`Span.total_counters`) merges them with the registry's semantics
 (:mod:`repro.obs.registry`: counters sum, gauges max/last/min).
 
+Every :class:`Span` also holds a ``jax.profiler.TraceAnnotation`` of its
+own name open while it runs, so a profiler capture carries the span tree
+on the trace's own clock, nested as here; outside a capture the
+annotation records nothing.
+
 The kill switch is the ``REPRO_OBS`` environment variable (``off``,
 ``0``, ``false``, ``no`` disable; anything else enables — the default).
 Tests and benchmarks can flip it at runtime with :func:`set_enabled` /
-the :func:`disabled` context manager.
+the :func:`disabled` context manager.  Disabled, no span and no
+annotation is made.
 """
 
 from __future__ import annotations
@@ -44,6 +50,8 @@ import contextlib
 import dataclasses
 import os
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 def _env_enabled() -> bool:
@@ -98,10 +106,12 @@ def current_span():
 class Span:
     """One timed node of the trace tree.
 
-    Use as a context manager: ``__enter__`` stamps ``t0`` and links the
-    span under the innermost active span (if any); ``__exit__`` stamps
-    ``t1``.  ``counters`` accumulate sums, ``gauges`` record last-written
-    values; both are merged over subtrees with the registry's semantics.
+    Use as a context manager: ``__enter__`` stamps ``t0``, links the
+    span under the innermost active span (if any) and opens a profiler
+    annotation of the span's name; ``__exit__`` stamps ``t1`` and closes
+    the annotation, also when the block raised.  ``counters`` accumulate
+    sums, ``gauges`` record last-written values; both are merged over
+    subtrees with the registry's semantics.
     """
 
     name: str
@@ -121,11 +131,17 @@ class Span:
         if stack:
             stack[-1].children.append(self)
         stack.append(self)
+        # A plain attribute, not a field, held only while the span is
+        # open: eq/asdict/to_dict ignore it and finished trees pickle.
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        del self._annotation
         stack = _STATE.stack
         if stack and stack[-1] is self:
             stack.pop()

@@ -4,6 +4,7 @@ metric merge semantics, manifest round-trip, and REPRO_OBS=off parity
 
 import json
 import os
+import pickle
 
 import numpy as np
 import pytest
@@ -11,7 +12,7 @@ import pytest
 from repro import obs
 from repro.core import PartitionPipeline
 from repro.dist.partition_aware import plan_halo_sharding
-from repro.mesh import dual_graph, pebble_mesh
+from repro.mesh import box_mesh, dual_graph, pebble_mesh
 
 
 @pytest.fixture(autouse=True)
@@ -176,13 +177,34 @@ def test_validate_manifest_flags_missing_stage_span(tmp_path):
         "pre": "rcb", "bisect": "rsb-batched", "post": ["repair"]})
     problems = obs.validate_manifest(path)
     missing = {p.split("'")[1] for p in problems if "missing span" in p}
-    assert missing == {"bisect:rsb-batched", "solve", "split", "post:repair"}
+    assert missing == {"bisect:rsb-batched", "solve", "split", "warm_start",
+                       "reorder", "sub", "post:repair"}
 
 
 def test_expected_span_names_from_config():
     names = obs.expected_span_names(
         {"pre": "none", "bisect": "rcb", "post": ["repair", "kway"]})
     assert names == {"partition", "bisect:rcb", "post:repair", "post:kway"}
+    # A guarded single-component rsb-batched run above the dense cutoff
+    # needs every host step of the level loop and of the batched solve.
+    default = {"pre": "rcb", "bisect": "rsb-batched", "post": ["repair"],
+               "n": 4096, "guard": True, "components": 1,
+               "method": "lanczos"}
+    assert obs.expected_span_names(default) == {
+        "partition", "guard:validate", "validate", "dual_graph",
+        "components", "guard:finalize", "pre:rcb", "bisect:rsb-batched",
+        "solve", "split", "warm_start", "reorder", "sub", "pack",
+        "restarts", "post:repair"}
+    # No geometric pre stage: no reorder or relabel.  Inverse iteration,
+    # or a root at the dense cutoff: no packed Lanczos solve.
+    for change, gone in (({"pre": "none"}, {"pre:rcb", "reorder", "sub"}),
+                         ({"method": "inverse"}, {"pack", "restarts"}),
+                         ({"n": 192}, {"pack", "restarts"}),
+                         ({"components": 2}, {
+                             "solve", "split", "warm_start", "reorder",
+                             "sub", "pack", "restarts"})):
+        names = obs.expected_span_names({**default, **change})
+        assert names == obs.expected_span_names(default) - gone, change
 
 
 # ---------------------------------------------------------------------------
@@ -272,3 +294,146 @@ def test_percentiles_nearest_rank():
     assert p["p50"] == 50.0
     assert p["p99"] == 99.0
     assert obs.percentiles([]) == {"p50": 0.0, "p99": 0.0}
+
+
+# ---------------------------------------------------------------------------
+# Host steps of the default pipeline, and the profiler annotations
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def box_call():
+    """The default pipeline, guard on, on a 512-element cube in 8 parts:
+    levels 0 and 1 solve packed Lanczos, level 2's 128-element nodes are
+    at the dense cutoff."""
+    from repro.configs.parrsb import make_pipeline
+
+    mesh = box_mesh(8, 8, 8)
+    return mesh, make_pipeline("default", guard=True).run(mesh, 8)
+
+
+def _children(span) -> list:
+    return [c.name for c in span.children]
+
+
+def test_default_call_has_a_span_at_every_host_step(box_call):
+    _, ctx = box_call
+    root = ctx.trace
+    assert _children(root.find("guard:validate")) == [
+        "validate", "dual_graph", "components"]
+    levels = [s for s in root.walk() if s.name.startswith("level:")]
+    assert [s.name for s in levels] == ["level:0", "level:1", "level:2"]
+    for lv in levels:
+        assert _children(lv) == ["reorder", "sub", "solve", "split"]
+    for lv in levels[:2]:
+        assert _children(lv.find("solve")) == ["warm_start", "pack",
+                                               "restarts"]
+    assert _children(levels[2].find("solve")) == ["warm_start"]
+    for s in root.walk():
+        for c in s.children:
+            assert s.t0 <= c.t0 <= c.t1 <= s.t1, (s.name, c.name)
+            assert c.seconds <= s.seconds
+    assert [lv.window for lv in ctx.report.levels] == [20, 20, 0]
+    # A finished tree holds no profiler annotation: it pickles.
+    copy = pickle.loads(pickle.dumps(root))
+    assert [s.name for s in copy.walk()] == [s.name for s in root.walk()]
+
+
+def test_restart_launches_count_the_restart_program(box_call, monkeypatch):
+    from repro.configs.parrsb import make_pipeline
+    from repro.core import lanczos
+
+    launches = []
+    real = lanczos._packed_restart
+
+    def counting(*args, **kw):
+        launches.append(1)
+        return real(*args, **kw)
+
+    monkeypatch.setattr(lanczos, "_packed_restart", counting)
+    mesh, _ = box_call
+    ctx = make_pipeline("default", guard=True).run(mesh, 8)
+    per_level = [s.counters["restart_launches"]
+                 for s in ctx.trace.find_all("restarts")]
+    assert len(per_level) == 2
+    assert ctx.trace.total_counters()["restart_launches"] == \
+        sum(per_level) == len(launches)
+    # Every subproblem rides each launch: a level launches as often as
+    # its slowest subproblem restarts.
+    for level, n in enumerate(per_level):
+        assert n == max(r.iterations for r in ctx.report.records
+                        if r.level == level)
+
+
+def test_default_call_labels_do_not_depend_on_obs(box_call):
+    from repro.configs.parrsb import make_pipeline
+
+    mesh, ctx_on = box_call
+    with obs.disabled():
+        ctx_off = make_pipeline("default", guard=True).run(mesh, 8)
+    assert ctx_off.trace is None
+    assert np.array_equal(ctx_on.parts, ctx_off.parts)
+    assert np.array_equal(ctx_on.parts_raw, ctx_off.parts_raw)
+
+
+def test_a_profiler_capture_holds_the_span_tree(box_call, tmp_path):
+    import jax
+
+    from repro.configs.parrsb import make_pipeline
+
+    mesh, _ = box_call
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        ctx = make_pipeline("default", guard=True).run(mesh, 8)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+               for f in fs if f.endswith(".xplane.pb")]
+    data = jax.profiler.ProfileData.from_file(path)
+    found: dict = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in ("partition", "solve", "restarts"):
+                    found.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    for name in ("partition", "solve", "restarts"):
+        assert len(found[name]) == len(ctx.trace.find_all(name)), name
+
+    def inside(child, parents):
+        return sum(s <= child[0] and child[1] <= e for s, e in parents) == 1
+
+    assert all(inside(r, found["solve"]) for r in found["restarts"])
+    assert all(inside(s, found["partition"]) for s in found["solve"])
+
+
+def test_a_span_that_raises_closes_its_annotation(monkeypatch):
+    import importlib
+
+    obs_trace = importlib.import_module("repro.obs.trace")
+    log = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name, exc[0]))
+            return False
+
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", Recorder)
+    with pytest.raises(ValueError):
+        with obs.trace("root"):
+            with obs.timed("inner"):
+                raise ValueError("boom")
+    assert log == [("enter", "root"), ("enter", "inner"),
+                   ("exit", "inner", ValueError), ("exit", "root", ValueError)]
+    # With obs off no span, and so no annotation, is made.
+    log.clear()
+    with obs.disabled():
+        with obs.trace("root"), obs.timed("inner"), obs.span("s"):
+            pass
+    assert log == []
