@@ -59,21 +59,83 @@ def _weighted_split(keys: np.ndarray, weights: np.ndarray,
     return order[:k], order[k:]
 
 
-def _bisect_order(coords, weights, idx, *, inertial):
-    """Iterative recursive-bisection ordering (DFS, left-half first)."""
-    stack = [idx]
-    ordered = []
-    while stack:
-        cur = stack.pop()
-        if cur.size <= 1:
-            ordered.append(cur)
-            continue
-        keys = _axis_key(coords[cur], weights[cur], inertial=inertial)
-        lo, hi = _weighted_split(keys, weights[cur], 0.5)
-        # push right first so left pops first (DFS left-to-right)
-        stack.append(cur[hi])
-        stack.append(cur[lo])
-    return np.concatenate(ordered) if ordered else idx
+def _segment_rescale(coords, starts, ends):
+    """Rescale every segment by its own bounding-box span (zero spans → 1)."""
+    full = ends > starts
+    if not full.any():
+        return coords
+    lo = starts[full]
+    span = np.maximum.reduceat(coords, lo) - np.minimum.reduceat(coords, lo)
+    span = np.where(span > 0, span, 1.0)
+    return coords / np.repeat(span, (ends - starts)[full], axis=0)
+
+
+def _segment_keys(c, w, seg, offs, *, inertial):
+    """Each segment's sort key: the coordinate along its longest axis (the
+    first on ties), or its projection on the principal inertial axis."""
+    if inertial:
+        wn = w / np.add.reduceat(w, offs)[seg]
+        mean = np.add.reduceat(c * wn[:, None], offs)
+        cen = c - mean[seg]
+        cov = np.add.reduceat((cen * wn[:, None])[:, :, None] * cen[:, None, :],
+                              offs)
+        axis = np.linalg.eigh(cov)[1][:, :, -1]
+        return np.einsum("ij,ij->i", c, axis[seg])
+    extent = np.maximum.reduceat(c, offs) - np.minimum.reduceat(c, offs)
+    return c[np.arange(c.shape[0]), np.argmax(extent, axis=1)[seg]]
+
+
+def rcb_order_segments(coords: np.ndarray, weights: np.ndarray | None,
+                       bounds, *, inertial: bool = False,
+                       rescale: bool = True) -> tuple[np.ndarray, int]:
+    """Recursive-bisection orders of k segments at once, one tree depth
+    at a time over every segment.
+
+    Segment s is rows ``bounds[s]:bounds[s+1]``.  Each pass bisects every
+    segment of two or more rows: stable sort on its key, then a split at
+    the first prefix holding half its weight, left half first, written back
+    in place.  The result is the order a left-first DFS of per-segment
+    bisections gives, so a node of m elements takes ⌈log₂ m⌉ passes (unit
+    weights) instead of m − 1 loop turns.
+
+    Returns ``(order, passes)``: ``order`` permutes 0..n-1 and maps each
+    segment's rows onto its own rows; ``passes`` counts the depth passes.
+    """
+    coords = np.asarray(coords, dtype=np.float64)
+    n = coords.shape[0]
+    w = np.ones(n) if weights is None else np.asarray(weights, dtype=np.float64)
+    bounds = np.asarray(bounds, dtype=np.int64)
+    starts, ends = bounds[:-1], bounds[1:]
+    if rescale:
+        coords = _segment_rescale(coords, starts, ends)
+    order = np.arange(n, dtype=np.int64)
+    passes = 0
+    while True:
+        live = ends - starts >= 2
+        starts, ends = starts[live], ends[live]
+        if not starts.size:
+            return order, passes
+        sizes = ends - starts
+        offs = np.cumsum(sizes) - sizes
+        seg = np.repeat(np.arange(sizes.size), sizes)
+        local = np.arange(seg.size) - offs[seg]
+        pos = starts[seg] + local
+        cur = order[pos]
+        key = _segment_keys(coords[cur], w[cur], seg, offs, inertial=inertial)
+        cur = cur[np.lexsort((key, seg))]
+        order[pos] = cur
+        # Per-segment prefix sums in rows of their own, so each equals
+        # np.cumsum of the segment alone whatever the weights.
+        rows = np.zeros((sizes.size, int(sizes.max())))
+        rows[seg, local] = w[cur]
+        cw = np.cumsum(rows, axis=1)
+        half = 0.5 * cw[np.arange(sizes.size), sizes - 1]
+        # searchsorted(cw, half, "left") + 1, clamped to [1, size - 1]
+        k = np.clip((cw < half[:, None]).sum(axis=1) + 1, 1, sizes - 1)
+        mid = starts + k
+        starts = np.stack([starts, mid], axis=1).ravel()
+        ends = np.stack([mid, ends], axis=1).ravel()
+        passes += 1
 
 
 def rcb_order(coords: np.ndarray, weights: np.ndarray | None = None, *,
@@ -84,13 +146,9 @@ def rcb_order(coords: np.ndarray, weights: np.ndarray | None = None, *,
     scale — the property both the pre-partitioner and the AMG aggregation
     bootstrap rely on.
     """
-    coords = np.asarray(coords, dtype=np.float64)
-    if rescale:
-        coords = _global_rescale(coords)
-    n = coords.shape[0]
-    w = np.ones(n) if weights is None else np.asarray(weights, dtype=np.float64)
-    return _bisect_order(coords, w, np.arange(n, dtype=np.int64),
-                         inertial=inertial)
+    n = np.shape(coords)[0]
+    return rcb_order_segments(coords, weights, [0, n], inertial=inertial,
+                              rescale=rescale)[0]
 
 
 def rib_order(coords: np.ndarray, weights: np.ndarray | None = None,

@@ -8,7 +8,8 @@ splits communicators so their Fiedler solves run concurrently; Sphynx maps
 the same structure onto accelerator-batched linear algebra).  Each level:
 
   1. (optional) geometric pre-partitioning — RCB/RIB reorder of every
-     active node's elements (paper §8: ≈2× Lanczos speedup),
+     active node's elements, all nodes in one segmented call (paper §8:
+     ≈2× Lanczos speedup),
   2. every active subproblem is padded into a power-of-two
      (n_pad, width_pad) **shape bucket** and the whole bucket runs ONE
      jitted, vmapped Fiedler solve — batched ELL / gather-scatter Laplacian
@@ -58,7 +59,7 @@ from repro.core.fiedler import (
     fiedler_from_mesh,
     next_pow2,
 )
-from repro.core.rcb import rcb_order, rib_order
+from repro.core.rcb import rcb_order, rcb_order_segments, rib_order
 from repro.guard.errors import SolverBreakdown
 from repro.guard.policy import SolverGuard
 from repro.mesh.graphs import Graph, dual_graph_from_incidence, extract_subgraphs
@@ -565,9 +566,7 @@ def _rsb_graph_batched(
 
         active = [(graph, np.arange(n, dtype=np.int64), 0, nparts)]
         level = 0
-        reorder = None
-        if pre in ("rcb", "rib") and coords is not None:
-            reorder = rcb_order if pre == "rcb" else rib_order
+        reorder = pre in ("rcb", "rib") and coords is not None
         while active:
             solve_nodes = []
             for node in active:
@@ -580,12 +579,20 @@ def _rsb_graph_batched(
                 break
 
             with obs.span(f"level:{level}", nodes=len(solve_nodes)):
-                if reorder is not None:
+                if reorder:
                     # Two passes over the level's nodes, so the geometric
-                    # orders and the subgraph relabels are timed apart.
+                    # orders and the subgraph relabels are timed apart.  All
+                    # nodes are ordered in one segmented call.
                     with obs.timed("reorder", level=level):
-                        perms = [reorder(coords[idx], w[idx])
-                                 for _, idx, _, _ in solve_nodes]
+                        idxs = [idx for _, idx, _, _ in solve_nodes]
+                        cat = np.concatenate(idxs)
+                        bounds = np.cumsum([0] + [idx.size for idx in idxs])
+                        order, passes = rcb_order_segments(
+                            coords[cat], w[cat], bounds,
+                            inertial=pre == "rib")
+                        obs.counter_add("reorder_passes", passes)
+                        perms = [order[a:b] - a
+                                 for a, b in zip(bounds[:-1], bounds[1:])]
                     with obs.timed("sub", level=level):
                         solve_nodes = [
                             (g.sub(perm), idx[perm], p_lo, p_hi)

@@ -103,6 +103,12 @@ register("amg_levels", "gauge", agg="max",
 register("multilevel_levels", "gauge", agg="max",
          description="Coarse-to-fine warm-start hierarchy depth")
 
+# RSB level loop
+register("reorder_passes", "counter",
+         description="Depth passes of the segmented RCB/RIB reorder, "
+                     "one count per pass and level (⌈log₂ m⌉ for a level "
+                     "whose largest node holds m unit-weight elements)")
+
 # Refinement / k-way FM
 register("fm_moves", "counter",
          description="k-way FM moves kept after rollback")

@@ -364,6 +364,23 @@ def test_restart_launches_count_the_restart_program(box_call, monkeypatch):
                         if r.level == level)
 
 
+def test_reorder_passes_are_ceil_log2_of_the_largest_node(box_call):
+    from repro.analysis import analyze_paths
+
+    assert obs.lookup("reorder_passes").kind == "counter"
+    _, ctx = box_call
+    reorders = [s.find("reorder") for s in ctx.trace.walk()
+                if s.name.startswith("level:")]
+    assert len(reorders) == 3
+    for level, span in enumerate(reorders):
+        largest = max(r.size for r in ctx.report.records if r.level == level)
+        assert span.counters["reorder_passes"] == np.ceil(np.log2(largest))
+    assert ctx.trace.total_counters()["reorder_passes"] == 9 + 8 + 7
+    assert "reorder" in obs.expected_span_names(ctx.config)
+    src = os.path.dirname(os.path.dirname(os.path.abspath(obs.__file__)))
+    assert [f for f in analyze_paths([src]) if f.rule.startswith("OBS")] == []
+
+
 def test_default_call_labels_do_not_depend_on_obs(box_call):
     from repro.configs.parrsb import make_pipeline
 

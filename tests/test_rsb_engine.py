@@ -3,7 +3,9 @@
 
 import numpy as np
 import pytest
+from test_rcb_segmented import _reference_segments
 
+from repro.configs.parrsb import make_pipeline
 from repro.core import (
     fiedler_from_graph,
     fiedler_from_graph_batched,
@@ -238,3 +240,49 @@ def test_partition_front_door_engine_flag(box):
     assert counts.max() <= 1.06 * counts.mean()
     with pytest.raises(ValueError):
         rsb_partition_mesh(m, 4, engine="nope")
+
+
+# ---------------------------------------------------------------------------
+# Segmented level reorder: the labels of the per-node reorder it replaced
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def shuffled_cube():
+    m = box_mesh(16, 16, 16)
+    return m.take(np.random.default_rng(3).permutation(m.nelems))
+
+
+@pytest.mark.parametrize("nparts", [16, 64])
+def test_default_labels_match_per_node_reorder(shuffled_cube, nparts,
+                                               monkeypatch):
+    pipe = make_pipeline("default")
+    parts = pipe.run(shuffled_cube, nparts).parts
+    nodes = []
+
+    def per_node_reorder(coords, weights, bounds, *, inertial):
+        assert not inertial
+        nodes.append(len(bounds) - 1)
+        return _reference_segments(coords, weights, bounds), 0
+
+    monkeypatch.setattr("repro.core.rsb.rcb_order_segments", per_node_reorder)
+    assert np.array_equal(parts, pipe.run(shuffled_cube, nparts).parts)
+    assert nodes == [2 ** lv for lv in range(int(np.log2(nparts)))]
+
+
+@pytest.mark.parametrize("engine", ["batched", "recursive"])
+def test_rib_balance_every_level(box, engine):
+    m, _ = box
+    for nparts in (4, 8, 16):
+        parts, _ = rsb_partition_mesh(m, nparts, pre="rib", tol=1e-2,
+                                      max_restarts=10, engine=engine)
+        assert _ancestor_balance_ok(parts, nparts), (engine, nparts)
+
+
+def test_rib_engine_cut_parity_pebble(pebble):
+    m, g = pebble
+    pb, rb = rsb_partition_mesh(m, 8, pre="rib", tol=1e-3, engine="batched")
+    pr, _ = rsb_partition_mesh(m, 8, pre="rib", tol=1e-3, engine="recursive")
+    cb = partition_metrics(g, pb, 8).edge_cut
+    cr = partition_metrics(g, pr, 8).edge_cut
+    assert cb <= 1.05 * cr and cr <= 1.05 * cb
+    assert rb.pre == "rib"
