@@ -3,11 +3,15 @@
     python3 perfbench/pb_control.py --workload box32.p16.default \\
         --sound 1,2,3,4,5,6,7,8,9,10,11,12 --control 1,2,3 --fault 1,2,3
 
-One process reads, for the cell:
+The cell's configuration may hold a hex mesh (``mesh``) or an undirected
+graph of a graph kind (``graph``, ``perfbench/graphs/<kind>.py``).  One
+process reads, for the cell:
 
-- ``sound``: for each seed, one call of the program on the cell's mesh in
-  the element order of the seed's first timed call, and every number the
-  harness compares (``pb_harness.check_calls``);
+- ``sound``: for each seed, one call of the program on the cell's input
+  in the element or node order of the seed's first timed call, every
+  number the harness compares (``pb_harness.check_calls``), and the
+  reference's cut: ``rcb_cut``, plain RCB's, where the input has
+  coordinates, and ``ref_cut``, plain RSB's, for a graph;
 - ``control``: for each seed, a whole run of the harness with
   :class:`Bfloat16Control` in the program's place;
 - ``fault``: for each seed, a whole run of the harness with
@@ -36,6 +40,17 @@ for _var in ("OPENBLAS_NUM_THREADS", "OMP_NUM_THREADS", "MKL_NUM_THREADS"):
 import numpy as np  # noqa: E402
 
 
+def call_graph(obj):
+    """The reference graph of what one call was handed, in its order: the
+    dual graph of a ``HexMesh``, the adjacency of a ``Graph``."""
+    import pb_reference as ref
+
+    if hasattr(obj, "vert_gid"):
+        return ref.dual_graph(obj.vert_gid)
+    src = np.repeat(np.arange(obj.n), np.diff(obj.indptr))
+    return ref.graph_from_edges(obj.n, src, obj.indices)
+
+
 class Bfloat16Control:
     """The plain reference in the program's place for the λ₂ numbers: the
     program's labels, with the eigenvalue of every node of the bisection
@@ -46,13 +61,13 @@ class Bfloat16Control:
                  steps: int = 300):
         self.pipe, self.seed, self.dtype, self.steps = pipe, seed, dtype, steps
 
-    def run(self, mesh, nparts: int):
+    def run(self, obj, nparts: int, **kw):
         import jax.numpy as jnp
 
         import pb_reference as ref
 
-        ctx = self.pipe.run(mesh, nparts)
-        g = ref.dual_graph(mesh.vert_gid)
+        ctx = self.pipe.run(obj, nparts, **kw)
+        g = call_graph(obj)
         nodes = ref.tree_nodes(np.asarray(ctx.parts_raw), nparts)
         for (_, _, idx), rec in zip(nodes, ctx.report.records):
             rec.eigenvalue = ref.lanczos_lambda2(
@@ -89,14 +104,15 @@ class ScrambledSegment:
         finally:
             rsb.fiedler_from_graph_batched = solve
 
-    def run(self, mesh, nparts: int):
+    def run(self, obj, nparts: int, **kw):
         with self._broken():
-            return self.pipe.run(mesh, nparts)
+            return self.pipe.run(obj, nparts, **kw)
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--workload", required=True)
+    ap.add_argument("--workload", required=True,
+                    help="a cell of BENCHMARK.json, on a mesh or a graph kind")
     ap.add_argument("--sound", default="")
     ap.add_argument("--control", default="")
     ap.add_argument("--fault", default="")
@@ -111,24 +127,25 @@ def main(argv=None) -> int:
     devices = pb_harness.check_device(cell["chips"])
     nparts = int(traffic["nparts"])
     pipe = make_pipeline(traffic["preset"], **traffic.get("pipeline", {}))
-    mesh = pb_harness.base_mesh(config)
+    inp = pb_harness.base_input(config)
 
     def seeds(text):
         return [int(s) for s in text.split(",") if s]
 
     calls = []
     for seed in seeds(args.sound):
-        perm = pb_harness.seed_rng(seed, 1).permutation(mesh.nelems)
-        calls.append(pb_harness.timed_call(pipe, mesh.take(perm), nparts,
+        perm = pb_harness.seed_rng(seed, 1).permutation(inp.n)
+        calls.append(pb_harness.timed_call(pipe, inp.call(perm), nparts,
                                            perm))
     if calls:
-        checks, _, _, info = pb_harness.check_calls(calls, mesh, nparts,
+        checks, _, _, info = pb_harness.check_calls(calls, inp, nparts,
                                                     config)
+        cuts = {k: info[k] for k in ("ref_cut", "rcb_cut") if k in info}
         for seed, c, row in zip(seeds(args.sound), calls, info["rows"]):
             print(json.dumps({"workload": args.workload, "kind": "sound",
                               "seed": seed, "seconds": c.seconds,
                               "device": devices[0].device_kind,
-                              "numbers": row,
+                              "numbers": row, **cuts,
                               "limits": {k: v[1] for k, v in checks.items()}}),
                   flush=True)
     with open(os.devnull, "w") as quiet:

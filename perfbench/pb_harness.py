@@ -1,16 +1,18 @@
 """The benchmark harness: one cell, one seed, one run.
 
 A cell of ``BENCHMARK.json`` names a configuration (``configs/<name>.json``:
-the mesh and the checks it states) and a traffic mix
+the input and the checks it states) and a traffic mix
 (``traffic/<name>.json``: the part count, the pipeline preset and, under
 ``pipeline``, keywords that ``make_pipeline`` lays over the preset).  The
-run is a closed loop of partition calls, one at a time, as a
-spectral-element code makes them: set-up builds the mesh and warms up one
-call; the window then runs calls back to back, each on the same mesh in a
-fresh element order drawn from ``(seed, call)``, until the calls' summed
-durations reach ``--seconds``.  A call is
-``make_pipeline(preset).run(mesh, nparts)`` up to the host holding the
-labels.
+input is a hex mesh (a ``mesh`` entry, :mod:`pb_mesh`) or an undirected
+graph (a ``graph`` entry, :mod:`pb_graph`).  The run is a closed loop of
+partition calls, one at a time, as a spectral-element code makes them:
+set-up builds the input and warms up one call; the window then runs calls
+back to back, each on the same input in a fresh element or node order
+drawn from ``(seed, call)``, until the calls' summed durations reach
+``--seconds``.  A call is ``make_pipeline(preset).run(input, nparts)``,
+with a graph's node weights and any coordinates, up to the host holding
+the labels.
 
 After the window every call's labels are checked by the plain reference
 (:mod:`pb_reference`), and the Fiedler eigenvalue that the call reports
@@ -39,13 +41,13 @@ COMPILE_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 
 class SetupError(RuntimeError):
-    """The run cannot start: no accelerator, or fewer chips than the cell
-    asks for."""
+    """The run cannot start: no accelerator, fewer chips than the cell asks
+    for, or an input the configuration cannot have."""
 
 
 @dataclasses.dataclass
 class Call:
-    """What one timed call produced, in the base mesh's element order."""
+    """What one timed call produced, in the base input's order."""
 
     t0: float                 # perf_counter at the call's start
     t1: float
@@ -69,7 +71,7 @@ class Run:
 
     calls: list
     nparts: int
-    graph: object             # pb_reference.DualGraph of the base mesh
+    graph: object             # pb_reference.DualGraph of the base input
     device_kind: str
     events: list | None = None    # pb_trace.Event rows (traced run)
     offset_ns: float = 0.0        # trace ns − perf_counter ns
@@ -130,6 +132,40 @@ def base_mesh(config: dict):
     vert, coords, weights = pb_mesh.load_kind(
         config["mesh"]["kind"]).build(config["mesh"])
     return pb_mesh.hex_mesh(vert, coords, weights)
+
+
+MESH_CHECKS = ("balance_tol", "cut_vs_rcb", "lam2_rel_err",
+               "lam2_deep_rel_err")
+
+
+def check_names(config: dict, has_coords: bool) -> set:
+    """The limits a configuration's ``checks`` hold: a mesh's are
+    :data:`MESH_CHECKS`; a graph's compare its cut with that of the plain
+    RSB (``cut_vs_ref``), and also with that of plain RCB where the graph
+    has coordinates."""
+    if "mesh" in config:
+        return set(MESH_CHECKS)
+    names = {"balance_tol", "cut_vs_ref", "lam2_rel_err", "lam2_deep_rel_err"}
+    return names | {"cut_vs_rcb"} if has_coords else names
+
+
+def base_input(config: dict):
+    """The configuration's input in its base order: a
+    ``pb_mesh.MeshInput`` or a ``pb_graph.GraphInput``."""
+    if "graph" in config:
+        import pb_graph
+
+        inp = pb_graph.build(config["graph"])
+    else:
+        import pb_mesh
+
+        inp = pb_mesh.MeshInput(base_mesh(config))
+    want = check_names(config, inp.coords is not None)
+    if set(config["checks"]) != want:
+        raise SetupError(f"the configuration's checks are "
+                         f"{sorted(config['checks'])}; its input needs "
+                         f"{sorted(want)}")
+    return inp
 
 
 def check_device(chips: int):
@@ -193,10 +229,13 @@ def log(*args) -> None:
     print(*args, file=sys.stderr, flush=True)
 
 
-def timed_call(pipe, mesh, nparts: int, perm: np.ndarray) -> Call:
+def timed_call(pipe, args: tuple, nparts: int, perm: np.ndarray) -> Call:
+    """One call on ``args``, the ``(input, keywords)`` that the base
+    input's ``call(perm)`` prepared."""
+    obj, kw = args
     gc0, cpu0 = GC.seconds, time.process_time()
     t0 = time.perf_counter()
-    ctx = pipe.run(mesh, nparts)
+    ctx = pipe.run(obj, nparts, **kw)
     parts = np.asarray(ctx.parts)
     t1 = time.perf_counter()
     cpu_s, gc_s = time.process_time() - cpu0, GC.seconds - gc0
@@ -240,34 +279,50 @@ def lambda2_errors(c: Call, lam2, nparts: int) -> tuple[float, float]:
     return errs[0], max(errs[1:], default=0.0)
 
 
-def check_calls(calls: list, mesh, nparts: int, config: dict) -> tuple:
+def check_calls(calls: list, inp, nparts: int, config: dict) -> tuple:
     """Every call's checks against the plain reference: ``(checks, failed
     calls, reference graph, info)``, where ``checks`` maps each number
-    compared to ``[worst over the calls, limit]``."""
+    compared to ``[worst over the calls, limit]``, and ``info`` holds the
+    reference cuts (``ref_cut``, the plain RSB's, for a graph input;
+    ``rcb_cut`` where the input has coordinates) and ``ref_s``, the plain
+    RSB's seconds."""
     import pb_reference as ref
 
     lim = config["checks"]
     limits = {"out_of_range": 0, "empty_parts": 0, "disconnected_parts": 0,
-              "balance": lim["balance_tol"], "cut_vs_rcb": lim["cut_vs_rcb"],
-              "lam2_rel_err": lim["lam2_rel_err"],
-              "lam2_deep_rel_err": lim["lam2_deep_rel_err"]}
-    g = ref.dual_graph(mesh.vert_gid)
-    w = np.asarray(mesh.weights, np.float64)
-    rcb_cut = ref.edge_cut(g, ref.rcb_labels(mesh.coords, w, nparts))
-    lam2 = ref.NodeLambda2(g)
+              "balance": lim["balance_tol"]}
+    limits.update((k, lim[k]) for k in ("cut_vs_ref", "cut_vs_rcb")
+                  if k in lim)
+    limits.update(lam2_rel_err=lim["lam2_rel_err"],
+                  lam2_deep_rel_err=lim["lam2_deep_rel_err"])
+    g = inp.reference_graph()
+    w = inp.weights
+    info = {}
+    if "cut_vs_ref" in limits:
+        t0 = time.perf_counter()
+        info["ref_cut"] = ref.edge_cut(g, ref.rsb_labels(g, w, nparts))
+        info["ref_s"] = time.perf_counter() - t0
+        log(f"plain RSB reference {info['ref_s']:.3f} s")
+    if "cut_vs_rcb" in limits:
+        info["rcb_cut"] = ref.edge_cut(
+            g, ref.rcb_labels(inp.coords, w, nparts))
+    lam2 = ref.NodeLambda2(g, None if "mesh" in config
+                           else lambda sub: ref.fiedler_pair(sub)[0])
     rows = []
     for c in calls:
         r = ref.check_partition(g, c.labels, w, nparts, lim["balance_tol"])
-        r["cut_vs_rcb"] = r["cut"] / rcb_cut
+        if "ref_cut" in info:
+            r["cut_vs_ref"] = r["cut"] / info["ref_cut"]
+        if "rcb_cut" in info:
+            r["cut_vs_rcb"] = r["cut"] / info["rcb_cut"]
         r["lam2_rel_err"], r["lam2_deep_rel_err"] = lambda2_errors(
             c, lam2, nparts)
         rows.append(r)
     failed = sum(not all(r[k] <= v for k, v in limits.items()) for r in rows)
     checks = {k: [max(r[k] for r in rows), v] for k, v in limits.items()}
-    info = {"rcb_cut": rcb_cut, "lambda2": lam2.top(),
-            "cuts": [r["cut"] for r in rows],
-            "rows": [{k: r[k] for k in limits} for r in rows],
-            "residual": [c.records[0]["residual"] for c in calls]}
+    info.update(lambda2=lam2.top(), cuts=[r["cut"] for r in rows],
+                rows=[{k: r[k] for k in limits} for r in rows],
+                residual=[c.records[0]["residual"] for c in calls])
     return checks, failed, g, info
 
 
@@ -278,8 +333,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     """One run; prints per-call lines and returns the result object.
 
     ``bench``/``config``/``traffic`` and ``pipeline`` replace what the
-    cell names (tests drive a small mesh and a broken pipeline through the
-    rest of a run); ``require_chip=False`` skips the look for a chip.
+    cell names (tests drive a small input and a broken pipeline through
+    the rest of a run); ``require_chip=False`` skips the look for a chip.
     """
     if bench is None:
         bench, cell, config, traffic = resolve_cell(root, workload)
@@ -296,17 +351,17 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     nparts = int(traffic["nparts"])
     pipe = pipeline or make_pipeline(traffic["preset"],
                                      **traffic.get("pipeline", {}))
-    mesh = base_mesh(config)
-    n = mesh.nelems
-    log(f"{workload}: {n} elements into {nparts} parts, preset "
+    inp = base_input(config)
+    n = inp.n
+    log(f"{workload}: {n} {inp.noun} into {nparts} parts, preset "
         f"{traffic['preset']}, {len(devices)} x {kind}, cache {cache}")
 
     perm = seed_rng(seed, 0).permutation(n)
-    warm = timed_call(pipe, mesh.take(perm), nparts, perm)
+    warm = timed_call(pipe, inp.call(perm), nparts, perm)
     log(f"warm-up call {warm.seconds:.3f} s, {compiles.count} programs "
         "lowered")
     perm = seed_rng(seed, 1).permutation(n)
-    nxt = mesh.take(perm)
+    nxt = inp.call(perm)
     setup_s = time.perf_counter() - t_start
     lowered_before = compiles.count
 
@@ -326,7 +381,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             print(call_line(len(calls) - 1, c), file=out, flush=True)
             if window < seconds:
                 perm = seed_rng(seed, len(calls) + 1).permutation(n)
-                nxt = mesh.take(perm)
+                nxt = inp.call(perm)
     lowered = compiles.count - lowered_before
     log(f"window {window:.3f} s, {len(calls)} calls, {lowered} programs "
         "lowered inside the window")
@@ -335,9 +390,10 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(devices), "memory_peak_bytes": int(peak)}
 
-    checks, failed, graph, info = check_calls(calls, mesh, nparts, config)
-    log(f"lambda2 reference {info['lambda2']!r}, plain RCB cut "
-        f"{info['rcb_cut']!r}")
+    checks, failed, graph, info = check_calls(calls, inp, nparts, config)
+    log(f"lambda2 reference {info['lambda2']!r}, "
+        + ", ".join(f"plain {name} cut {info[k]!r}" for k, name in
+                    (("ref_cut", "RSB"), ("rcb_cut", "RCB")) if k in info))
     run = Run(calls=calls, nparts=nparts, graph=graph, device_kind=kind)
     result = {"correct": False, "attempted": len(calls), "failed": failed,
               "metrics": {}, "device": device}
@@ -373,7 +429,9 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
                       "lam2_deep_rel_err": [r["lam2_deep_rel_err"]
                                             for r in rows],
                       "top_residual": info["residual"],
-                      "lambda2": info["lambda2"], "rcb_cut": info["rcb_cut"],
+                      "lambda2": info["lambda2"],
+                      **{k: info[k] for k in ("ref_cut", "rcb_cut", "ref_s")
+                         if k in info},
                       "setup_s": setup_s,
                       "lowered_in_window": lowered}), file=out, flush=True)
     ok = all(v <= lim for v, lim in checks.values())

@@ -4,15 +4,19 @@ A mesh kind is a file ``perfbench/meshes/<kind>.py`` whose
 ``build(spec) -> (vert_gid, coords, weights)`` turns the ``mesh`` entry of
 a configuration file into an ``(E, 8)`` corner-id table, element
 centroids and element weights.  :func:`hex_mesh` numbers edges and faces
-from the corners and hands the program its input type.
+from the corners and hands the program its input type; :class:`MeshInput`
+holds it for the harness.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import importlib.util
 import os
 
 import numpy as np
+
+import pb_reference
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -76,3 +80,31 @@ def hex_mesh(vert_gid: np.ndarray, coords: np.ndarray, weights: np.ndarray):
                    weights=np.ascontiguousarray(weights, np.float64),
                    n_vert=n_vert, n_edge=n_edge, n_face=n_face)
 
+
+
+@dataclasses.dataclass(frozen=True)
+class MeshInput:
+    """A configuration's mesh in its base element order."""
+
+    mesh: object                  # the program's HexMesh
+    noun = "elements"
+
+    @property
+    def n(self) -> int:
+        return self.mesh.nelems
+
+    @property
+    def weights(self) -> np.ndarray:
+        return np.asarray(self.mesh.weights, np.float64)
+
+    @property
+    def coords(self) -> np.ndarray:
+        return self.mesh.coords
+
+    def call(self, perm: np.ndarray) -> tuple[object, dict]:
+        """What one call hands ``pipe.run``: the mesh in the element order
+        ``perm``."""
+        return self.mesh.take(perm), {}
+
+    def reference_graph(self) -> pb_reference.DualGraph:
+        return pb_reference.dual_graph(self.mesh.vert_gid)

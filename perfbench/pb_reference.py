@@ -1,16 +1,21 @@
-"""Plain reference for the partition cells: dual graph, checker, λ₂.
+"""Plain reference for the partition cells: dual graph, checker, λ₂, RSB.
 
 Nothing here imports the program under test.  The semantics follow the
 parRSB paper: two hex elements are adjacent when they share a vertex, and
 the edge weight ω is the number of vertices they share (1, 2 or 4).  A
-partition is sound when every label lies in ``[0, nparts)``, every part is
-non-empty and connected in the dual graph, and every part's weight lies
-inside ``(1 ± balance_tol)`` of the mean.  Its cut is the ω-weighted count
-of dual-graph edges whose ends lie in different parts.
+graph input (:func:`graph_from_edges`) is its own adjacency, every edge of
+weight 1.  A partition is sound when every label lies in ``[0, nparts)``,
+every part is non-empty and connected in the graph, and every part's
+weight lies inside ``(1 ± balance_tol)`` of the mean.  Its cut is the
+ω-weighted count of edges whose ends lie in different parts, compared with
+the cut of plain recursive coordinate bisection (:func:`rcb_labels`) where
+the input has coordinates, and with that of plain float64 recursive
+spectral bisection (:func:`rsb_labels`) for a graph input.
 
 The Fiedler reference is λ₂ of the Laplacian ``L = D − A`` in float64
-(ARPACK through SciPy, or a dense solve when small), of the dual graph and
-of each subgraph that a node of the bisection tree induces.  :func:`lanczos_lambda2` is a plain
+(ARPACK through SciPy for a mesh, :func:`fiedler_pair` for a graph, or a
+dense solve when small), of the whole input and of each subgraph that a
+node of the bisection tree induces.  :func:`lanczos_lambda2` is a plain
 windowed Lanczos in JAX that takes a dtype: at bfloat16 it is the control
 that the λ₂ comparison has to fail.
 """
@@ -20,6 +25,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
+import warnings
 
 import numpy as np
 import scipy.sparse as sp
@@ -58,6 +64,22 @@ def dual_graph(vert_gid: np.ndarray) -> DualGraph:
     shared.setdiag(0)
     shared.eliminate_zeros()
     return DualGraph(adj=shared)
+
+
+def graph_from_edges(n: int, src: np.ndarray, dst: np.ndarray) -> DualGraph:
+    """The graph of an undirected edge list over ``n`` nodes: an edge in
+    either direction, or in both, or listed more than once, is one edge of
+    weight 1; self-loops are dropped; each row's columns are sorted."""
+    src = np.asarray(src, np.int64).ravel()
+    dst = np.asarray(dst, np.int64).ravel()
+    keep = src != dst
+    src, dst = src[keep], dst[keep]
+    adj = sp.csr_matrix((np.ones(2 * src.size), (np.r_[src, dst],
+                                                 np.r_[dst, src])),
+                        shape=(n, n))
+    adj.sum_duplicates()
+    adj.data[:] = 1.0
+    return DualGraph(adj=adj)
 
 
 def edge_cut(g: DualGraph, labels: np.ndarray) -> float:
@@ -118,10 +140,12 @@ def subgraph(g: DualGraph, idx: np.ndarray) -> DualGraph:
 
 class NodeLambda2:
     """λ₂ of the subgraphs of one graph, each solved once and kept by its
-    element set."""
+    element set, by ``solve`` (:func:`lambda2_reference`, or for a graph
+    input with hubs the λ₂ of :func:`fiedler_pair`)."""
 
-    def __init__(self, g: DualGraph):
+    def __init__(self, g: DualGraph, solve=None):
         self.g, self._seen = g, {}
+        self.solve = solve or lambda2_reference
 
     def __call__(self, idx: np.ndarray) -> tuple[float, float]:
         """``(λ₂, scale)`` of the subgraph ``idx`` induces.  The scale is
@@ -132,7 +156,7 @@ class NodeLambda2:
             sub = subgraph(self.g, idx)
             ncomp, _ = csgraph.connected_components(sub.adj, directed=False)
             if ncomp == 1:
-                lam = lambda2_reference(sub)
+                lam = self.solve(sub)
                 self._seen[key] = (lam, lam)
             else:
                 self._seen[key] = (0.0, float(sub.adj.sum()) / sub.n)
@@ -189,6 +213,77 @@ def rcb_labels(coords: np.ndarray, weights: np.ndarray,
         rec(order[k:], lo + p // 2, hi)
 
     rec(np.arange(coords.shape[0]), 0, nparts)
+    return labels
+
+
+def fiedler_pair(g: DualGraph) -> tuple[float, np.ndarray]:
+    """λ₂ of a connected graph's Laplacian in float64, and its
+    eigenvector, with the vector's sign fixed so that its entry of largest
+    magnitude is positive.  Dense up to ``DENSE_N`` nodes.  Above, LOBPCG
+    for the two lowest eigenpairs orthogonal to the constants, with the
+    inverse degrees as preconditioner: on a graph with hubs, ARPACK's
+    Lanczos needs many restarts, since λ_max, about the largest degree,
+    dwarfs the gap above λ₂.  Where LOBPCG leaves a residual
+    ``‖Lv − λ₂v‖`` above ``1e-6 · λ₂``, ARPACK solves it instead."""
+    if g.n <= DENSE_N:
+        vals, vecs = np.linalg.eigh(g.laplacian().toarray())
+        lam, v = float(vals[1]), vecs[:, 1]
+    else:
+        L = g.laplacian()
+        x0 = np.random.default_rng(0).standard_normal((g.n, 2))
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", UserWarning)
+            vals, vecs = sla.lobpcg(
+                L, x0, M=sp.diags(1.0 / L.diagonal()),
+                Y=np.ones((g.n, 1)), largest=False, tol=1e-8, maxiter=3000)
+        lam, v = float(vals[np.argmin(vals)]), vecs[:, np.argmin(vals)]
+        v = v / np.linalg.norm(v)
+        if not np.linalg.norm(L @ v - lam * v) <= 1e-6 * lam:
+            vals, vecs = sla.eigsh(L, k=3, which="SA", tol=1e-12, ncv=60,
+                                   maxiter=100_000, v0=x0[:, 0])
+            i = np.argsort(vals)[1]
+            lam, v = float(vals[i]), vecs[:, i]
+    return lam, (v if v[np.argmax(np.abs(v))] > 0 else -v)
+
+
+def spectral_order(g: DualGraph) -> np.ndarray:
+    """The nodes of ``g`` in the order that its bisection splits.  A
+    connected graph is ordered by its Fiedler vector.  A disconnected one
+    is ordered by (component, Fiedler value within the component): the
+    components in the order of SciPy's labels (the component of node 0
+    first, then that of the lowest node not yet labelled, and so on),
+    each by the Fiedler vector of its own subgraph, a node alone by 0."""
+    ncomp, comp = csgraph.connected_components(g.adj, directed=False)
+    if ncomp == 1:
+        return np.argsort(fiedler_pair(g)[1], kind="stable")
+    value = np.zeros(g.n)
+    for c in range(ncomp):
+        idx = np.flatnonzero(comp == c)
+        if idx.size > 1:
+            value[idx] = fiedler_pair(subgraph(g, idx))[1]
+    return np.lexsort((value, comp))
+
+
+def rsb_labels(g: DualGraph, weights: np.ndarray,
+               nparts: int) -> np.ndarray:
+    """Plain recursive spectral bisection in float64: order each tree
+    node's subgraph by :func:`spectral_order` and split it at the weighted
+    point that gives the halves ⌊p/2⌋ and ⌈p/2⌉ of the weight."""
+    labels = np.zeros(g.n, dtype=np.int64)
+
+    def rec(idx, lo, hi):
+        p = hi - lo
+        if p <= 1 or idx.size <= 1:
+            labels[idx] = lo
+            return
+        order = idx[spectral_order(subgraph(g, idx))]
+        cw = np.cumsum(weights[order])
+        k = int(np.searchsorted(cw, cw[-1] * (p // 2) / p)) + 1
+        k = min(max(k, 1), idx.size - 1)
+        rec(order[:k], lo, lo + p // 2)
+        rec(order[k:], lo + p // 2, hi)
+
+    rec(np.arange(g.n), 0, nparts)
     return labels
 
 

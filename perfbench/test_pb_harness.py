@@ -33,21 +33,35 @@ def test_every_named_file_loads():
     for c in bench["configs"]:
         cfg = pb_harness.load_json(os.path.join(ROOT, c["file"]))
         assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
-        assert set(cfg["checks"]) == {"balance_tol", "cut_vs_rcb",
-                                      "lam2_rel_err", "lam2_deep_rel_err"}
+        assert ("mesh" in cfg) != ("graph" in cfg)
+        if "mesh" in cfg:
+            assert set(cfg["checks"]) == {"balance_tol", "cut_vs_rcb",
+                                          "lam2_rel_err", "lam2_deep_rel_err"}
+        else:
+            import pb_graph
+
+            g = pb_graph.build(cfg["graph"])
+            assert set(cfg["checks"]) == {
+                "balance_tol", "cut_vs_ref", "lam2_rel_err",
+                "lam2_deep_rel_err"} | (
+                    {"cut_vs_rcb"} if g.coords is not None else set())
     for w in bench["workloads"]:
         _, cell, cfg, traffic = pb_harness.resolve_cell(ROOT, w["name"])
         assert cell == w and traffic["nparts"] >= 2
+        import pb_graph
         import pb_mesh
 
-        assert callable(pb_mesh.load_kind(cfg["mesh"]["kind"]).build)
+        kinds = pb_mesh if "mesh" in cfg else pb_graph
+        spec = cfg["mesh"] if "mesh" in cfg else cfg["graph"]
+        assert callable(kinds.load_kind(spec["kind"]).build)
     for m in bench["per_layer"]:
         assert callable(pb_harness.load_reader(m["name"]).read)
 
 
 def test_new_cells_are_files_and_entries(tmp_path):
     """A configuration, a traffic mix and a metric are added by adding
-    files and entries; no file that is there changes."""
+    files and entries; so are a graph configuration, its graph kind and
+    its cell; no file that is there changes."""
     root = tmp_path / "checkout"
     shutil.copytree(os.path.join(ROOT, "perfbench"), root / "perfbench",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -63,12 +77,28 @@ def test_new_cells_are_files_and_entries(tmp_path):
         {"loop": "closed", "clients": 1, "nparts": 4, "preset": "default"}))
     (root / "perfbench/metrics/calls.py").write_text(
         "def read(run):\n    return float(len(run.calls))\n")
+    (root / "perfbench/graphs/ring.py").write_text(
+        "import numpy as np\n\n\ndef build(spec):\n"
+        "    n = spec['nodes']\n"
+        "    return n, np.arange(n), (np.arange(n) + 1) % n, np.ones(n), "
+        "None\n")
+    (root / "perfbench/configs/ring64.json").write_text(json.dumps({
+        "name": "ring64", "graph": {"kind": "ring", "nodes": 64},
+        "reduced": [], "checks": {"balance_tol": 0.05, "cut_vs_ref": 1.5,
+                                  "lam2_rel_err": 0.01,
+                                  "lam2_deep_rel_err": 0.01}}))
     bench["configs"].append({"name": "box8", "source": "test",
                              "file": "perfbench/configs/box8.json",
+                             "reduced": [], "why": "test"})
+    bench["configs"].append({"name": "ring64", "source": "test",
+                             "file": "perfbench/configs/ring64.json",
                              "reduced": [], "why": "test"})
     bench["workloads"].append({"name": "box8.p4.default", "config": "box8",
                                "traffic": "p4.default", "chips": 1,
                                "why": "test"})
+    bench["workloads"].append({"name": "ring64.p4.default",
+                               "config": "ring64", "traffic": "p4.default",
+                               "chips": 1, "why": "test"})
     bench["per_layer"].append({"name": "calls", "unit": "count",
                                "better": "higher", "source": "host_clock",
                                "layer": "test", "moves": "partition_s"})
@@ -78,8 +108,13 @@ def test_new_cells_are_files_and_entries(tmp_path):
         import importlib
 
         h = importlib.reload(importlib.import_module("pb_harness"))
+        importlib.reload(importlib.import_module("pb_graph"))
         _, cell, cfg, traffic = h.resolve_cell(str(root), "box8.p4.default")
         assert cfg["mesh"]["dims"] == [8, 8, 8] and traffic["nparts"] == 4
+        _, cell, cfg, traffic = h.resolve_cell(str(root), "ring64.p4.default")
+        ring = h.base_input(cfg)
+        assert (ring.n, ring.adj.nnz, ring.coords) == (64, 128, None)
+        assert traffic["nparts"] == 4
         names = [m["name"] for m in h.cell_metrics(bench, "box8.p4.default",
                                                    trace=True)]
         assert names == ["calls"]
@@ -89,6 +124,7 @@ def test_new_cells_are_files_and_entries(tmp_path):
     finally:
         sys.path.remove(str(root / "perfbench"))
         importlib.reload(importlib.import_module("pb_harness"))
+        importlib.reload(importlib.import_module("pb_graph"))
     for p, data in before.items():
         assert p.read_bytes() == data
 
@@ -161,13 +197,17 @@ class Broken:
 
         self.pipe, self.fault = make_pipeline("default"), fault
 
-    def run(self, mesh, nparts):
-        ctx = self.pipe.run(mesh, nparts)
+    def run(self, obj, nparts, **kw):
+        ctx = self.pipe.run(obj, nparts, **kw)
         parts = ctx.parts.copy()
         n = parts.size
         if self.fault == "label":          # one answer altered
-            # Element 0 goes to a part that none of its neighbours is in.
-            near = np.isin(mesh.vert_gid, mesh.vert_gid[0]).any(axis=1)
+            # Element or node 0 goes to a part that none of its neighbours
+            # is in.
+            if hasattr(obj, "vert_gid"):
+                near = np.isin(obj.vert_gid, obj.vert_gid[0]).any(axis=1)
+            else:
+                near = np.r_[0, obj.indices[obj.indptr[0]:obj.indptr[1]]]
             parts[0] = np.setdiff1d(np.arange(nparts), parts[near])[0]
         elif self.fault == "half":          # half of the batch left out
             parts[n // 2:] = 0
@@ -233,3 +273,89 @@ def test_the_bfloat16_control_is_not_correct(tmp_path, restore_cache_config):
         assert r["correct"] is correct
         deep = r["checks"]["lam2_deep_rel_err"]
         assert (deep["value"] > deep["limit"]) is not correct
+
+
+# -- the same on a graph input ----------------------------------------------
+
+# A 24 x 10 lattice without coordinates in 4 parts.  On the CPU sound runs
+# read 1.0 on the cut against the plain RSB's and 7e-7 / 7e-15 on the λ₂
+# numbers; the bfloat16 control 0.21 on the top node and 0.096 below it
+# (float32: 2.3e-5 / 3.2e-6); the scrambled segment 0.48 on the balance,
+# which the repair stage trades for connected parts.
+TINY_GRAPH_CONFIG = {
+    "graph": {"kind": "grid", "dims": [24, 10], "coords": False},
+    "checks": {"balance_tol": 0.05, "cut_vs_ref": 1.5,
+               "lam2_rel_err": 0.005, "lam2_deep_rel_err": 0.005}}
+
+
+def _run_graph(tmp_path, pipeline=None, coords=False):
+    config = copy.deepcopy(TINY_GRAPH_CONFIG)
+    if coords:
+        config["graph"]["coords"] = True
+        config["checks"]["cut_vs_rcb"] = 1.5
+    out = open(os.devnull, "w")
+    try:
+        return pb_harness.run_cell(
+            str(tmp_path), "tiny", 2**31 + 11, 0.3, False,
+            t_start=time.perf_counter(), require_chip=False,
+            pipeline=pipeline, bench=copy.deepcopy(TINY_BENCH),
+            config=config, traffic=TINY_TRAFFIC, out=out)
+    finally:
+        out.close()
+
+
+@pytest.mark.parametrize("coords", [False, True])
+def test_a_sound_graph_run_is_correct(tmp_path, restore_cache_config,
+                                      coords):
+    r = _run_graph(tmp_path, coords=coords)
+    assert r["correct"] and r["failed"] == 0 and r["attempted"] >= 1
+    assert list(r)[-1] == "checks"
+    assert set(r["checks"]) == {
+        "out_of_range", "empty_parts", "disconnected_parts", "balance",
+        "cut_vs_ref", "lam2_rel_err", "lam2_deep_rel_err"} | (
+            {"cut_vs_rcb"} if coords else set())
+    assert set(r["metrics"]) == {"partition_s", "edge_cut", "setup_s"}
+    # Every edge of the lattice weighs 1: the cut counts edges.
+    assert r["metrics"]["edge_cut"]["value"] == int(
+        r["metrics"]["edge_cut"]["value"])
+
+
+@pytest.mark.parametrize("fault, check", [
+    ("label", "disconnected_parts"), ("half", "balance"),
+    ("range", "out_of_range"), ("eigenvalue", "lam2_rel_err"),
+    ("deep-eigenvalue", "lam2_deep_rel_err"), ("records", "lam2_rel_err")])
+def test_a_broken_graph_timed_path_is_not_correct(
+        tmp_path, restore_cache_config, fault, check):
+    r = _run_graph(tmp_path, Broken(fault))
+    assert not r["correct"] and r["failed"] == r["attempted"] >= 1
+    assert r["checks"][check]["value"] > r["checks"][check]["limit"]
+
+
+def test_a_scrambled_segment_fails_the_graph_balance(tmp_path,
+                                                     restore_cache_config):
+    from repro.configs.parrsb import make_pipeline
+
+    r = _run_graph(tmp_path, pb_control.ScrambledSegment(
+        make_pipeline("default"), 2**31 + 3))
+    assert not r["correct"] and r["failed"] == r["attempted"] >= 1
+    assert r["checks"]["balance"]["value"] > r["checks"]["balance"]["limit"]
+
+
+def test_the_bfloat16_control_fails_a_graph(tmp_path, restore_cache_config):
+    from repro.configs.parrsb import make_pipeline
+
+    for dtype, correct in (("bfloat16", False), ("float32", True)):
+        r = _run_graph(tmp_path, pb_control.Bfloat16Control(
+            make_pipeline("default"), 2**31 + 7, dtype=dtype))
+        assert r["correct"] is correct
+        deep = r["checks"]["lam2_deep_rel_err"]
+        assert (deep["value"] > deep["limit"]) is not correct
+
+
+@pytest.mark.parametrize("config, extra", [
+    (TINY_GRAPH_CONFIG, "cut_vs_rcb"), (TINY_CONFIG, "cut_vs_ref")])
+def test_checks_that_do_not_fit_the_input_are_refused(config, extra):
+    config = copy.deepcopy(config)
+    config["checks"][extra] = 1.5
+    with pytest.raises(pb_harness.SetupError):
+        pb_harness.base_input(config)

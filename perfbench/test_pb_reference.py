@@ -139,3 +139,85 @@ def test_a_bfloat16_top_level_eigenvalue_fails(box):
         f32 = ref.lanczos_lambda2(g, dtype=jnp.float32, steps=120, seed=seed)
         assert abs(bf16 - lam) / lam > 3 * limit
         assert abs(f32 - lam) / lam < limit / 3
+
+
+# -- graph inputs ------------------------------------------------------------
+
+def test_graph_from_edges_is_symmetric_with_unit_edges():
+    # (0, 1) twice and in both directions, (2, 2) a self-loop.
+    g = ref.graph_from_edges(4, np.array([0, 1, 0, 2, 2, 3]),
+                             np.array([1, 0, 1, 2, 3, 1]))
+    a = g.adj.toarray()
+    assert (a == a.T).all() and set(np.unique(a)) == {0.0, 1.0}
+    assert np.trace(a) == 0 and g.nnz == 6
+    assert ref.edge_cut(g, np.array([0, 0, 1, 1])) == 1.0
+
+
+def _dense_rsb(g, weights, nparts):
+    """Recursive bisection by the dense float64 Fiedler vector."""
+    labels = np.zeros(g.n, dtype=np.int64)
+
+    def rec(idx, lo, hi):
+        p = hi - lo
+        if p <= 1:
+            labels[idx] = lo
+            return
+        lap = ref.subgraph(g, idx).laplacian().toarray()
+        order = idx[np.argsort(np.linalg.eigh(lap)[1][:, 1], kind="stable")]
+        cw = np.cumsum(weights[order])
+        k = int(np.searchsorted(cw, cw[-1] * (p // 2) / p)) + 1
+        rec(order[:k], lo, lo + p // 2)
+        rec(order[k:], lo + p // 2, hi)
+
+    rec(np.arange(g.n), 0, nparts)
+    return labels
+
+
+def _parts(labels):
+    return {frozenset(np.flatnonzero(labels == p)) for p in set(labels)}
+
+
+@pytest.mark.parametrize("nparts", [2, 4, 8])
+def test_rsb_labels_against_a_dense_fiedler_split(nparts):
+    """On a 32 x 20 lattice (640 nodes, above the dense cutoff, so that the
+    top node takes the sparse solve) every split falls between whole
+    columns or rows of tied Fiedler values."""
+    import pb_graph
+
+    g = pb_graph.build({"kind": "grid", "dims": [32, 20], "coords": False})
+    g, w = g.reference_graph(), g.weights
+    got = ref.rsb_labels(g, w, nparts)
+    assert _parts(got) == _parts(_dense_rsb(g, w, nparts))
+    assert ref.edge_cut(g, got) == {2: 20, 4: 20 + 2 * 16,
+                                     8: 20 + 2 * 16 + 4 * 10}[nparts]
+
+
+def test_fiedler_pair_against_a_dense_solve(monkeypatch):
+    """The sparse solve, and the ARPACK solve that takes over where LOBPCG
+    leaves a large residual, against ``eigh`` on a graph with hubs."""
+    import pb_graph
+
+    g = pb_graph.build({"kind": "rmat", "scale": 10, "edge_factor": 8,
+                        "abcd": [0.57, 0.19, 0.19, 0.05], "graph_seed": 3})
+    g = g.reference_graph()
+    assert g.n > ref.DENSE_N
+    vals, vecs = np.linalg.eigh(g.laplacian().toarray())
+    lam, v = ref.fiedler_pair(g)
+    assert lam == pytest.approx(vals[1], rel=1e-9)
+    assert abs(v @ vecs[:, 1]) == pytest.approx(1.0, abs=1e-6)
+    assert v[np.argmax(np.abs(v))] > 0
+    monkeypatch.setattr(ref.sla, "lobpcg",
+                        lambda A, X, **kw: (np.ones(2), X))
+    lam2, v2 = ref.fiedler_pair(g)
+    assert lam2 == pytest.approx(vals[1], rel=1e-9)
+    assert v2 @ v == pytest.approx(1.0, abs=1e-6)
+
+
+def test_a_disconnected_node_is_ordered_by_component_then_fiedler():
+    # Component 0: a path 0-2-4-6; component 1: the path 1-3-5.
+    g = ref.graph_from_edges(7, np.array([0, 2, 4, 1, 3]),
+                             np.array([2, 4, 6, 3, 5]))
+    order = ref.spectral_order(g)
+    assert set(order[:4]) == {0, 2, 4, 6} and set(order[4:]) == {1, 3, 5}
+    assert order[:4].tolist() in ([0, 2, 4, 6], [6, 4, 2, 0])
+    assert order[4:].tolist() in ([1, 3, 5], [5, 3, 1])
