@@ -716,7 +716,7 @@ def fiedler_from_graph_batched(
         return results
 
     if method == "lanczos":
-        with obs.timed("pack"):
+        with obs.timed("pack") as t_pack:
             sizes = [graphs[i].n for i in solve_ix]
             offs, N, n_seg, seg, mask = _pack_layout(sizes, pack_slots,
                                                      pack_segs)
@@ -737,6 +737,14 @@ def fiedler_from_graph_batched(
         packed = _solve_packed_lanczos(
             op, offs, N, n_seg, seg, mask, b0, sizes, tol, window, max_restarts
         )
+        # Every restart launch gathers over all N × width slots once per
+        # Lanczos step: count the slots and the real entries among them.
+        launches = max(r.iterations for r in packed)
+        if isinstance(t_pack, obs.Span):
+            for name, v in (("ell_slots", N * width),
+                            ("ell_nnz", sum(graphs[i].nnz for i in solve_ix))):
+                t_pack.counters[name] = (t_pack.counters.get(name, 0.0)
+                                         + float(v * launches))
         for r, i in enumerate(solve_ix):
             results[i] = packed[r]
             results[i].levels = ml_levels[i]

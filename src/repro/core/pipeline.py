@@ -409,9 +409,8 @@ def run_post_stages(
     """Run an ordered chain of registered post stages over ``parts``.
 
     The balance corridor is computed ONCE here — from the part weights the
-    chain starts with — and threaded through every stage, so a
-    cap-exceeding forced move in one stage cannot widen the corridor for
-    the stages after it (callers may pre-seed ``post_kw["corridor"]`` to
+    chain starts with — and threaded through every stage, so one stage's
+    moves cannot shift the corridor for the stages after it (callers may pre-seed ``post_kw["corridor"]`` to
     pin an even earlier reference).  Returns the refined labels, the
     aggregated :class:`PostStats`, and one :class:`StageRecord` per stage.
 
@@ -436,7 +435,6 @@ def run_post_stages(
         parts = np.asarray(parts, dtype=np.int64)
         agg.stages.append(name)
         agg.fragments_repaired += stats.fragments_repaired
-        agg.forced_moves += stats.forced_moves
         # final state, not a sum: a later repair can clear earlier
         # stages' leftovers
         agg.unrepaired_fragments = stats.unrepaired_fragments

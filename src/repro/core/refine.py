@@ -13,10 +13,13 @@ dual graph, host-side NumPy, as pipeline `post` stages:
   heaviest component, and reassign every other fragment to the neighboring
   part with the maximum shared edge weight (ties toward the lighter part).
   A fragment has *zero* edges to the rest of its own part, so each move
-  strictly decreases the cut by the shared weight — repair can only
-  improve the cut, and it terminates (the cut is bounded below).  Moves
-  prefer destinations that stay under the balance cap; when no sharing
-  part fits, connectivity wins and the move is recorded as *forced*.
+  strictly decreases the cut by the shared weight.  A move must keep both
+  parts inside the balance corridor: the destination at or under the cap,
+  the source at or over the floor.  A fragment with no such destination
+  stays where it is and is counted in ``unrepaired_fragments``: the
+  corridor is never traded for connectivity, so a bisection that leaves
+  more orphans than the corridor has room for has to be mended where it
+  splits (``core/split.py``), not here.
 
 * **Greedy weighted boundary refinement** (:func:`refine_boundary`) —
   Fiduccia–Mattheyses-style single-node moves over the boundary frontier.
@@ -35,16 +38,18 @@ The balance corridor is computed ONCE per post chain — from the part
 weights the chain starts with — and threaded through every stage via the
 ``corridor=`` keyword (the pipeline does this; so do :func:`refine_stage`
 and :func:`repair_refine` for their internal sub-passes).  Recomputing it
-per stage would let a cap-exceeding forced repair move permanently widen
-the cap for every later stage.  Each stage records the corridor it used in
+per stage would let one stage's moves shift the cap and floor for every
+later stage.  Each stage records the corridor it used in
 ``PostStats.corridor``.
 
 Single-node moves can disconnect a part (moving an articulation node), so
 :func:`refine_stage` — the "refine" stage the pipeline registers — closes
-its FM sweeps with a repair pass: the invariant handed downstream is
-**zero disconnected parts** (on a globally connected graph) at a cut no
-worse than the bisection's.  :func:`repair_refine` composes the default
-post pair (repair, then refine_stage) as one call for direct library use.
+its FM sweeps with a repair pass, and where that pass cannot reconnect a
+part inside the corridor it sweeps again from its input, moving only
+nodes whose loss keeps their part connected: handed connected parts, it
+hands on connected parts, at a cut no worse than the bisection's.
+:func:`repair_refine` composes the default post pair (repair, then
+refine_stage) as one call for direct library use.
 """
 
 from __future__ import annotations
@@ -73,8 +78,8 @@ class PostStats:
 
     stages: list = dataclasses.field(default_factory=list)  # stage names run
     fragments_repaired: int = 0
-    forced_moves: int = 0        # fragment moves that had to exceed the cap
-    unrepaired_fragments: int = 0  # left behind when repair's round cap hit
+    unrepaired_fragments: int = 0  # left in place: no room in the corridor,
+                                   # or repair's round cap hit
     moves_applied: int = 0       # FM single-node moves (kway: kept moves)
     sweeps: list = dataclasses.field(default_factory=list)  # [SweepRecord]
     corridor: tuple | None = None  # (floor, cap) the stage enforced
@@ -88,7 +93,6 @@ class PostStats:
         return {
             "stages": list(self.stages),
             "fragments_repaired": self.fragments_repaired,
-            "forced_moves": self.forced_moves,
             "unrepaired_fragments": self.unrepaired_fragments,
             "moves_applied": self.moves_applied,
             "sweeps": [dataclasses.asdict(s) for s in self.sweeps],
@@ -108,7 +112,6 @@ class PostStats:
         raw row dict — consumers read it like ``KwayStats.row()``)."""
         s = cls(stages=list(d.get("stages", [])),
                 fragments_repaired=d.get("fragments_repaired", 0),
-                forced_moves=d.get("forced_moves", 0),
                 unrepaired_fragments=d.get("unrepaired_fragments", 0),
                 moves_applied=d.get("moves_applied", 0),
                 corridor=tuple(d["corridor"]) if d.get("corridor") else None,
@@ -167,15 +170,19 @@ def repair_components(
     max_rounds: int = 8,
 ) -> tuple[np.ndarray, PostStats]:
     """Reassign every disconnected fragment to its best-connected neighbor
-    part.  Strictly cut-decreasing; see the module docstring for the move
-    rule.  Rounds iterate because a receiving part may itself have lost its
-    anchoring fragment in the same round; convergence is typically 1–2
-    rounds (each round strictly decreases the cut).
+    part that has room for it: the destination stays at or under the
+    corridor's cap, the source at or over its floor (module docstring).
+    Every move decreases the cut.  A fragment without such a destination
+    is left in place and counted in ``unrepaired_fragments``.  Rounds
+    iterate because a receiving part may itself have lost its anchoring
+    fragment in the same round, and because a move can make room for
+    another; they stop when a round moves nothing.
 
     ``corridor`` is the post chain's fixed (floor, cap); when None (direct
     library call outside a chain) it is computed from the incoming labels.
     Fragments with no cut edges at all (islands of a globally disconnected
-    graph) are left in place — no reassignment can connect them.
+    graph) are left in place — no reassignment can connect them.  The
+    counter ``repair_moved`` adds up the node weight moved.
     """
     parts = np.asarray(parts, dtype=np.int64).copy()
     n = graph.n
@@ -184,13 +191,13 @@ def repair_components(
     part_w = _part_weights(parts, w, nparts)
     if corridor is None:
         corridor = _balance_corridor(part_w, balance_tol)
-    _, cap = corridor
+    floor, cap = corridor
     stats = PostStats(stages=["repair"], corridor=tuple(corridor),
                       cut_before=edge_cut(graph, parts))
+    moved_w = 0.0
     with obs.timed("repair") as t:
-        deferred = 0
         for round_no in range(max_rounds):
-            deferred = 0
+            left = 0
             intra = parts[rows] == parts[cols]
             comp = connected_labels(n, rows[intra], cols[intra])
             n_comp = int(comp.max()) + 1 if n else 0
@@ -228,36 +235,33 @@ def repair_components(
                     # connected to them, so its zero-internal-edge premise (the
                     # strict-cut-decrease argument) no longer holds.  Defer to
                     # the next round, which recomputes components.
-                    deferred += 1
+                    left += 1
                     continue
                 cand = np.flatnonzero(shared[k] > 0)
-                if cand.size == 0:
-                    continue  # island: no foreign edges to follow
                 fw = comp_w[f]
                 fits = cand[part_w[cand] + fw <= cap]
-                pool = fits if fits.size else cand
-                best_shared = shared[k, pool].max()
-                ties = pool[shared[k, pool] == best_shared]
+                if fits.size == 0 or part_w[src] - fw < floor:
+                    if cand.size:       # no room: the corridor holds
+                        left += 1
+                    continue            # (an island has nowhere to go)
+                best_shared = shared[k, fits].max()
+                ties = fits[shared[k, fits] == best_shared]
                 tgt = int(ties[np.argmin(part_w[ties])])  # ties → lighter part
-                if not fits.size:
-                    stats.forced_moves += 1
                 parts[comp == f] = tgt
                 part_w[tgt] += fw
                 part_w[src] -= fw
+                moved_w += fw
                 received[tgt] = True
                 stats.fragments_repaired += 1
                 moved_any = True
+            stats.unrepaired_fragments = left
             if not moved_any:
                 break
-        else:
-            # Round cap hit with fragments still deferred: the contract
-            # (zero disconnected parts) is broken — make it diagnosable.
-            stats.unrepaired_fragments = deferred
 
         stats.cut_after = edge_cut(graph, parts)
+        obs.counter_add("repair_moved", moved_w)
     stats.seconds = t.seconds
     obs.counter_add("fragments_repaired", stats.fragments_repaired)
-    obs.counter_add("forced_moves", stats.forced_moves)
     return parts, stats
 
 
@@ -270,6 +274,7 @@ def refine_boundary(
     sweeps: int = 4,
     balance_tol: float = 0.05,
     corridor: tuple | None = None,
+    keep_connected: bool = False,
 ) -> tuple[np.ndarray, PostStats]:
     """Greedy weighted FM-style boundary refinement (module docstring).
 
@@ -277,7 +282,8 @@ def refine_boundary(
     each under a stale-gain guard (skip if a neighbor already moved this
     sweep) and the weight-balance corridor.  A candidate whose
     best-connected target would overflow the cap falls back to the best
-    *feasible* positive-gain target.
+    *feasible* positive-gain target.  ``keep_connected`` also skips every
+    node whose loss would disconnect its part.
     """
     parts = np.asarray(parts, dtype=np.int64).copy()
     n = graph.n
@@ -335,6 +341,8 @@ def refine_boundary(
                 fits = pos[part_w[pos] + wn <= cap]
                 if fits.size == 0:
                     continue
+                if keep_connected and not _stays_connected(graph, parts, node):
+                    continue
                 tgt = int(fits[np.argmax(row[fits])])
                 parts[node] = tgt
                 part_w[tgt] += wn
@@ -373,7 +381,6 @@ def close_with_repair(
     parts, r = repair_components(graph, parts, nparts, weights=weights,
                                  balance_tol=balance_tol, corridor=corridor)
     stats.fragments_repaired += r.fragments_repaired
-    stats.forced_moves += r.forced_moves
     stats.unrepaired_fragments = r.unrepaired_fragments
     stats.cut_after = r.cut_after
     stats.seconds += r.seconds
@@ -391,17 +398,45 @@ def refine_stage(
     corridor: tuple | None = None,
 ) -> tuple[np.ndarray, PostStats]:
     """The pipeline's "refine" stage: FM boundary sweeps + a closing repair
-    pass, so articulation moves cannot leave a disconnected part.  Both
-    passes are cut-non-increasing, so the stage is too.  One corridor
-    (computed here from the incoming labels unless the chain supplies it)
-    governs both passes."""
+    pass, so articulation moves cannot leave a disconnected part.  Where
+    the repair cannot reconnect a part inside the corridor and the
+    incoming labels had every part connected, the stage sweeps again from
+    the incoming labels with ``keep_connected``.  Both passes are
+    cut-non-increasing, so the stage is too.  One corridor (computed here
+    from the incoming labels unless the chain supplies it) governs both
+    passes."""
     if corridor is None:
         corridor = balance_corridor(parts, nparts, weights, balance_tol)
-    parts, stats = refine_boundary(graph, parts, nparts, weights=weights,
-                                   sweeps=sweeps, balance_tol=balance_tol,
-                                   corridor=corridor)
-    return close_with_repair(graph, parts, nparts, stats, weights=weights,
-                             balance_tol=balance_tol, corridor=corridor)
+    out, stats = refine_boundary(graph, parts, nparts, weights=weights,
+                                 sweeps=sweeps, balance_tol=balance_tol,
+                                 corridor=corridor)
+    out, stats = close_with_repair(graph, out, nparts, stats, weights=weights,
+                                   balance_tol=balance_tol, corridor=corridor)
+    if stats.unrepaired_fragments and _all_connected(graph, parts):
+        # The sweeps cut a part the repair cannot rejoin inside the
+        # corridor: sweep again from the incoming labels, moving only
+        # nodes whose loss keeps their part connected.
+        return refine_boundary(graph, parts, nparts, weights=weights,
+                               sweeps=sweeps, balance_tol=balance_tol,
+                               corridor=corridor, keep_connected=True)
+    return out, stats
+
+
+def _all_connected(graph: Graph, parts: np.ndarray) -> bool:
+    """Is every non-empty part's induced subgraph connected?"""
+    parts = np.asarray(parts, dtype=np.int64)
+    intra = parts[graph.rows] == parts[graph.indices]
+    comp = connected_labels(graph.n, graph.rows[intra], graph.indices[intra])
+    return int(comp.max()) + 1 == np.unique(parts).size if graph.n else True
+
+
+def _stays_connected(graph: Graph, parts: np.ndarray, node: int) -> bool:
+    """Does ``node``'s part stay connected without it?"""
+    rest = parts == parts[node]
+    rest[node] = False
+    keep = rest[graph.rows] & rest[graph.indices]
+    comp = connected_labels(graph.n, graph.rows[keep], graph.indices[keep])
+    return np.unique(comp[rest]).size <= 1
 
 
 def repair_refine(
@@ -430,13 +465,11 @@ def repair_refine(
             parts, r = repair_components(graph, parts, nparts, **kw)
             stats.stages.append("repair")
             stats.fragments_repaired += r.fragments_repaired
-            stats.forced_moves += r.forced_moves
             stats.unrepaired_fragments = r.unrepaired_fragments
         if refine:
             parts, f = refine_stage(graph, parts, nparts, sweeps=sweeps, **kw)
             stats.stages.append("refine")
             stats.fragments_repaired += f.fragments_repaired
-            stats.forced_moves += f.forced_moves
             stats.unrepaired_fragments = f.unrepaired_fragments
             stats.moves_applied += f.moves_applied
             stats.sweeps.extend(f.sweeps)

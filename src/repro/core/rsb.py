@@ -18,7 +18,16 @@ the same structure onto accelerator-batched linear algebra).  Each level:
      masks and per-subproblem convergence flags,
   3. a proportional split per node (sort by Fiedler component, cut at
      ⌊P/2⌋ / ⌈P/2⌉ of the weight — multi-material support) emits the next
-     level's subgraphs via one vectorized multi-subgraph extraction.
+     level's subgraphs via one vectorized multi-subgraph extraction, and
+     one labelling pass finds the components of all of them.  A node
+     whose subgraph is disconnected is not solved whole: its components
+     join the level's batch, it reports λ₂ = 0 and is cut in (component,
+     Fiedler value) order.  A node whose plain halves fall into pieces —
+     a hub-dominated graph, where the Fiedler vector puts the periphery
+     on one side and its hubs on the other — is split instead into
+     unions of parts grown connected along its Fiedler vector
+     (`core/split.py`); a mesh's halves are connected, so its splits are
+     the plain ones.
 
 Because the batched operators are *pytrees* handed to jit as traced
 arguments, the run compiles one trace per shape bucket — a constant number
@@ -52,6 +61,7 @@ import dataclasses
 import numpy as np
 
 from repro import obs
+from repro.core import split
 from repro.core.fiedler import (
     _DENSE_CUTOFF,
     fiedler_from_graph,
@@ -62,7 +72,13 @@ from repro.core.fiedler import (
 from repro.core.rcb import rcb_order, rcb_order_segments, rib_order
 from repro.guard.errors import SolverBreakdown
 from repro.guard.policy import SolverGuard
-from repro.mesh.graphs import Graph, dual_graph_from_incidence, extract_subgraphs
+from repro.mesh.graphs import (
+    Graph,
+    connected_labels,
+    dual_graph_from_incidence,
+    extract_subgraphs,
+    structural_order,
+)
 
 _ENGINES = ("batched", "recursive")
 
@@ -74,7 +90,8 @@ class BisectionRecord:
     nparts: int
     method: str
     iterations: int
-    eigenvalue: float
+    eigenvalue: float  # batched graph engine: the Rayleigh quotient of the
+                       # vector the split used (0 for a disconnected node)
     residual: float
     seconds: float
     levels: int = 0    # multilevel hierarchy depth (warm start or AMG); 0 = none
@@ -188,6 +205,64 @@ def _proportional_split(keys: np.ndarray, weights: np.ndarray, n_left: int,
     return order[:k], order[k:]
 
 
+def _rayleigh(g: Graph, v: np.ndarray) -> float:
+    """The Rayleigh quotient on ``g``'s Laplacian of ``v`` with its mean
+    removed, in float64: the λ₂ estimate of the vector a split is made
+    from.  For a Ritz pair it is the Ritz value, to rounding."""
+    v = np.asarray(v, np.float64)
+    v = v - v.mean()
+    av = np.bincount(g.rows, weights=g.weights * v[g.indices], minlength=g.n)
+    deg = np.bincount(g.rows, weights=g.weights, minlength=g.n)
+    vv = float(v @ v)
+    return float(v @ (deg * v - av)) / vv if vv > 0 else 0.0
+
+
+def _component_split(g: Graph, comp: np.ndarray, comp_groups: list,
+                     comp_results: list, weights: np.ndarray, n_left: int,
+                     n_total: int, ids: np.ndarray):
+    """Split a disconnected node: order it by (component, the Fiedler
+    value of the component's own subgraph), components by their lowest
+    of ``ids``, as the plain reference's spectral order does, and cut at
+    the weighted point."""
+    values = np.zeros(g.n)
+    for group, res in zip(comp_groups, comp_results):
+        values[group] = split.sign_fixed(res.vector)
+    keys = np.empty(g.n)
+    keys[split.component_order(comp, values, ids)] = np.arange(g.n)
+    return _proportional_split(keys, weights, n_left, n_total)
+
+
+def _planned_split(g: Graph, weights: np.ndarray, f: np.ndarray, p: int,
+                   plan: np.ndarray | None):
+    """Halves of a connected node as unions of parts grown along its
+    Fiedler vector ``f`` (:func:`split.part_plan`), with each half's plan:
+    ``(lo, hi, plan_lo, plan_hi)``.  Where the fresh plan fails, the
+    node's inherited ``plan`` is used; with none, returns None."""
+    fresh, ok = split.part_plan(g, weights, f, p)
+    if not ok:
+        if plan is None:
+            return None
+        fresh = plan
+    left, plan_lo, plan_hi = split.plan_halves(fresh, f, p)
+    return np.flatnonzero(left), np.flatnonzero(~left), plan_lo, plan_hi
+
+
+def _children_components(children: list) -> list:
+    """Component labels and counts of every child subgraph of a level, in
+    one labelling pass over the children's edges laid end to end."""
+    offs = np.cumsum([0] + [c.n for c in children])
+    src = np.concatenate([c.rows + o for c, o in zip(children, offs)])
+    dst = np.concatenate([c.indices + o for c, o in zip(children, offs)])
+    lab = connected_labels(int(offs[-1]), src, dst)
+    out = []
+    for a, b in zip(offs[:-1], offs[1:]):
+        # Labels rank the components by their lowest node, so each child's
+        # labels form one contiguous run.
+        local = lab[a:b] - (lab[a] if b > a else 0)
+        out.append((local, int(local.max()) + 1 if b > a else 0))
+    return out
+
+
 def _size_buckets(sizes: list) -> list:
     """Group node sizes into the (count, n_pad) shape buckets they solve in."""
     counts: dict = {}
@@ -222,7 +297,7 @@ def _levels_from_records(records: list) -> list:
 # ---------------------------------------------------------------------------
 
 def _resolve_solver_opts(window, max_restarts, multilevel, fine_restarts,
-                         ordered):
+                         ordered, coordless=False):
     """Multilevel solves are *refinements* of the prolonged coarse Fiedler
     vector: a shallower Lanczos window (cheaper restarts AND a cheaper
     compiled trace) capped at a few restarts replaces the deep cold-start
@@ -234,13 +309,19 @@ def _resolve_solver_opts(window, max_restarts, multilevel, fine_restarts,
     hierarchy — and hence the warm start — is weaker, and a capped
     refinement would freeze a poorer bisection.  Unordered runs (and runs
     whose warm start comes from elsewhere — callers pass ordered=False)
-    keep the multilevel seeding but solve to tolerance.  The one remaining
+    keep the multilevel seeding but solve to tolerance.  A graph without
+    coordinates (`coordless`) solves over a 100-step window: on a
+    skewed-degree graph, whose largest degree dwarfs the gap above λ₂ and
+    where λ₃ may lie within 1% of λ₂, 20-step windows run into the restart
+    cap short of tolerance (R-MAT scale 11: 50 restarts at each of the
+    top three levels), where 100-step ones converge in 1 to 4 restarts a
+    level for a fifth of the matvecs.  The one remaining
     capped-without-warm-start case is a per-problem
     `multilevel_warm_start` numerical-breakdown fallback to noise inside a
     packed solve (the cap is per call, not per problem) — rare enough that
     the balanced-but-coarser bisection it risks is accepted."""
     if window is None:
-        window = 20 if multilevel else 30
+        window = (100 if coordless else 20) if multilevel else 30
     if multilevel and ordered and fine_restarts is not None:
         max_restarts = min(max_restarts, fine_restarts)
     return window, max_restarts
@@ -458,6 +539,13 @@ def rsb_partition_graph(
     warm_start=True seeds each node's Fiedler solve from `coords` (the
     centroid coordinate along the subset's longest axis); it is a no-op
     without coords, and it takes precedence over the multilevel warm start.
+
+    Without `coords`, "rcb"/"rib" take the structural order of
+    :func:`repro.mesh.graphs.structural_order` in place of the geometric
+    one: the graph is relabelled by it before the first solve, so the
+    warm start's pairwise aggregation and the start vectors, which follow
+    the node order, do the same work whatever order the caller's nodes
+    come in.
     """
     if engine not in _ENGINES:
         raise ValueError(f"unknown engine: {engine}")
@@ -465,14 +553,27 @@ def rsb_partition_graph(
         window, max_restarts, multilevel, fine_restarts,
         ordered=(pre in ("rcb", "rib") and coords is not None
                  and not warm_start),
+        coordless=coords is None,
     )
+    order = None
+    if coords is None and pre in ("rcb", "rib"):
+        order = structural_order(graph)
+        graph = graph.sub(order)
+        if weights is not None:
+            weights = np.asarray(weights)[order]
     kw = dict(coords=coords, weights=weights, method=method, pre=pre, tol=tol,
               window=window, max_restarts=max_restarts, seed=seed,
               warm_start=warm_start, use_kernel=use_kernel,
               multilevel=multilevel, precond=precond, guard=guard)
     if engine == "batched":
-        return _rsb_graph_batched(graph, nparts, **kw)
-    return _rsb_graph_recursive(graph, nparts, **kw)
+        parts, report = _rsb_graph_batched(graph, nparts, ids=order, **kw)
+    else:
+        parts, report = _rsb_graph_recursive(graph, nparts, **kw)
+    if order is not None:
+        out = np.empty_like(parts)
+        out[order] = parts
+        parts = out
+    return parts, report
 
 
 def _rsb_graph_recursive(
@@ -547,9 +648,13 @@ def _rsb_graph_recursive(
 
 def _rsb_graph_batched(
     graph, nparts, *, coords, weights, method, pre, tol, window, max_restarts,
-    seed, warm_start, use_kernel, multilevel, precond, guard=None,
+    seed, warm_start, use_kernel, multilevel, precond, guard=None, ids=None,
 ) -> tuple[np.ndarray, RSBReport]:
+    """The level-synchronous engine (module docstring).  ``ids`` numbers
+    the nodes as the caller's graph does, where the engine runs on a
+    relabelled one: a disconnected node orders its components by them."""
     n = graph.n
+    ids = np.arange(n, dtype=np.int64) if ids is None else ids
     w = np.ones(n) if weights is None else np.asarray(weights, np.float64)
     records: list[BisectionRecord] = []
     levels: list[LevelRecord] = []
@@ -564,13 +669,18 @@ def _rsb_graph_batched(
         root_width = int(graph.degrees.max()) if graph.nnz else 1
         width_pad = next_pow2(max(root_width, 2))
 
-        active = [(graph, np.arange(n, dtype=np.int64), 0, nparts)]
+        # Each active node: (graph, element ids, p_lo, p_hi, plan, comp).
+        # `plan` is the part plan a connected split left this node (None
+        # where its parent split plainly); `comp` its (component labels,
+        # count), found once per level for all children together.
+        comp0 = split.components(graph)
+        active = [(graph, np.arange(n, dtype=np.int64), 0, nparts, None, comp0)]
         level = 0
         reorder = pre in ("rcb", "rib") and coords is not None
         while active:
             solve_nodes = []
             for node in active:
-                _, idx, p_lo, p_hi = node
+                _, idx, p_lo, p_hi, _, _ = node
                 if p_hi - p_lo <= 1 or idx.size <= 1:
                     parts[idx] = p_lo
                 else:
@@ -584,7 +694,7 @@ def _rsb_graph_batched(
                     # orders and the subgraph relabels are timed apart.  All
                     # nodes are ordered in one segmented call.
                     with obs.timed("reorder", level=level):
-                        idxs = [idx for _, idx, _, _ in solve_nodes]
+                        idxs = [node[1] for node in solve_nodes]
                         cat = np.concatenate(idxs)
                         bounds = np.cumsum([0] + [idx.size for idx in idxs])
                         order, passes = rcb_order_segments(
@@ -595,36 +705,64 @@ def _rsb_graph_batched(
                                  for a, b in zip(bounds[:-1], bounds[1:])]
                     with obs.timed("sub", level=level):
                         solve_nodes = [
-                            (g.sub(perm), idx[perm], p_lo, p_hi)
-                            for (g, idx, p_lo, p_hi), perm
+                            (g.sub(perm), idx[perm], p_lo, p_hi,
+                             None if plan is None else plan[perm],
+                             (comp[0][perm], comp[1]))
+                            for (g, idx, p_lo, p_hi, plan, comp), perm
                             in zip(solve_nodes, perms)]
+                # A disconnected node is not solved whole: each component
+                # of two or more nodes joins the level's batch instead.
+                with obs.timed("split_components", level=level):
+                    problems, owner, comp_parts = [], [], []
+                    for i, (g, idx, p_lo, p_hi, _, (lab, ncomp)) in enumerate(
+                            solve_nodes):
+                        if ncomp <= 1:
+                            owner.append((i, -1))
+                            problems.append(g)
+                            comp_parts.append(None)
+                            continue
+                        sizes = np.bincount(lab, minlength=ncomp)
+                        groups = [np.flatnonzero(lab == c)
+                                  for c in np.flatnonzero(sizes > 1)]
+                        comp_parts.append(groups)
+                        subs = extract_subgraphs(g, groups) if groups else []
+                        for k, sub in enumerate(subs):
+                            owner.append((i, k))
+                            problems.append(sub)
+                    obs.counter_add(
+                        "comp_split_nodes",
+                        sum(groups is not None for groups in comp_parts))
+                node_of = [solve_nodes[i] for i, _ in owner]
+                seeds = [_node_seed(seed, level, node[2], attempt=k + 1)
+                         if k >= 0 else _node_seed(seed, level, node[2])
+                         for node, (_, k) in zip(node_of, owner)]
                 with obs.timed("solve", level=level) as t_solve:
                     if sg is not None and sg.expired():
                         # Past the stage deadline: skip the level solve and
                         # let every node take the fallback rung below.
-                        results = [None] * len(solve_nodes)
+                        results = [None] * len(problems)
                     else:
                         results = fiedler_from_graph_batched(
-                            [g for g, _, _, _ in solve_nodes],
+                            problems,
                             method=method,
-                            seeds=[_node_seed(seed, level, p_lo)
-                                   for _, _, p_lo, _ in solve_nodes],
+                            seeds=seeds,
                             warms=[
-                                _warm_vector(coords[idx])
-                                if warm_start and coords is not None else None
-                                for _, idx, _, _ in solve_nodes
+                                _warm_vector(coords[node[1]])
+                                if warm_start and coords is not None
+                                and k < 0 else None
+                                for node, (_, k) in zip(node_of, owner)
                             ],
                             tol=tol, window=window, max_restarts=max_restarts,
                             pack_slots=pack_slots, pack_segs=pack_segs,
                             width_pad=width_pad, use_kernel=use_kernel,
                             multilevel=multilevel, precond=precond,
-                        )
+                        ) if problems else []
                 if sg is not None:
-                    # Re-admit every node's result; failed ones re-solve
+                    # Re-admit every result; failed ones re-solve
                     # individually through the escalation ladder.
                     rescued = []
-                    for (g, idx, p_lo, p_hi), res in zip(solve_nodes,
-                                                         results):
+                    for g, node, (_, k), res in zip(problems, node_of, owner,
+                                                    results):
                         def solve_fn(m, s, _g=g):
                             return fiedler_from_graph(
                                 _g, method=m, order=None, seed=s, tol=tol,
@@ -632,34 +770,93 @@ def _rsb_graph_batched(
                                 use_kernel=use_kernel, multilevel=multilevel,
                             )
                         rescued.append(_guarded(
-                            sg, res, solve_fn, level=level, p_lo=p_lo,
-                            size=int(idx.size),
-                            coords_sub=coords[idx]
-                            if coords is not None else None))
+                            sg, res, solve_fn, level=level, p_lo=node[2],
+                            size=g.n,
+                            coords_sub=coords[node[1]]
+                            if coords is not None and k < 0 else None))
                     results = rescued
+                node_results: list = [[] for _ in solve_nodes]
+                for (i, _), res in zip(owner, results):
+                    node_results[i].append(res)
                 with obs.timed("split", level=level) as t_split:
-                    next_active = []
-                    for (g, idx, p_lo, p_hi), res in zip(solve_nodes, results):
-                        np_here = p_hi - p_lo
+                    halves = []
+                    for node, groups, res_list in zip(solve_nodes, comp_parts,
+                                                      node_results):
+                        g, idx, p_lo, p_hi, plan, (lab, _) = node
+                        np_here, n_left = p_hi - p_lo, (p_hi - p_lo) // 2
+                        if groups is not None:
+                            records.append(BisectionRecord(
+                                level=level, size=int(idx.size),
+                                nparts=np_here, method="components",
+                                iterations=sum(r.iterations for r in res_list),
+                                eigenvalue=0.0, residual=0.0,
+                                seconds=t_solve.seconds / len(solve_nodes),
+                                levels=max((r.levels for r in res_list),
+                                           default=0),
+                                breakdown=any(r.breakdown for r in res_list),
+                            ))
+                            lo, hi = _component_split(
+                                g, lab, groups, res_list, w[idx], n_left,
+                                np_here, ids[idx])
+                            halves.append((lo, hi, None, None))
+                            continue
+                        res = res_list[0]
                         records.append(BisectionRecord(
                             level=level, size=int(idx.size), nparts=np_here,
                             method=res.method, iterations=res.iterations,
-                            eigenvalue=res.eigenvalue, residual=res.residual,
+                            eigenvalue=_rayleigh(g, res.vector),
+                            residual=res.residual,
                             seconds=t_solve.seconds / len(solve_nodes),
                             levels=res.levels, breakdown=res.breakdown,
                         ))
-                        n_left = np_here // 2
-                        lo, hi = _proportional_split(
-                            res.vector, w[idx], n_left, np_here)
-                        g_lo, g_hi = extract_subgraphs(g, [lo, hi])
-                        next_active.append((g_lo, idx[lo], p_lo, p_lo + n_left))
-                        next_active.append((g_hi, idx[hi], p_lo + n_left, p_hi))
+                        if plan is None:
+                            lo, hi = _proportional_split(
+                                res.vector, w[idx], n_left, np_here)
+                            halves.append((lo, hi, None, None))
+                        else:
+                            halves.append(_planned_split(
+                                g, w[idx], res.vector, np_here, plan))
+                    children = [c for node, (lo, hi, _, _)
+                                in zip(solve_nodes, halves)
+                                for c in extract_subgraphs(node[0], [lo, hi])]
+                    comps = _children_components(children)
+                    # Where a plain split left a half in pieces, grow the
+                    # node's parts as connected regions instead.
+                    grown = 0
+                    for i, node in enumerate(solve_nodes):
+                        g, idx, p_lo, p_hi, plan, (_, ncomp) = node
+                        if (plan is not None or ncomp > 1
+                                or comp_parts[i] is not None
+                                or (comps[2 * i][1] <= 1
+                                    and comps[2 * i + 1][1] <= 1)):
+                            continue
+                        fresh = _planned_split(g, w[idx],
+                                               node_results[i][0].vector,
+                                               p_hi - p_lo, None)
+                        if fresh is None:
+                            continue
+                        grown += 1
+                        halves[i] = fresh
+                        children[2 * i:2 * i + 2] = extract_subgraphs(
+                            g, [fresh[0], fresh[1]])
+                        comps[2 * i:2 * i + 2] = _children_components(
+                            children[2 * i:2 * i + 2])
+                    obs.counter_add("grown_splits", grown)
+                    next_active = []
+                    for i, (node, (lo, hi, plan_lo, plan_hi)) in enumerate(
+                            zip(solve_nodes, halves)):
+                        g, idx, p_lo, p_hi, _, _ = node
+                        mid = p_lo + (p_hi - p_lo) // 2
+                        next_active.append((children[2 * i], idx[lo], p_lo,
+                                            mid, plan_lo, comps[2 * i]))
+                        next_active.append((children[2 * i + 1], idx[hi], mid,
+                                            p_hi, plan_hi, comps[2 * i + 1]))
             levels.append(LevelRecord(
                 level=level,
                 n_nodes=len(solve_nodes),
-                total_size=sum(int(idx.size) for _, idx, _, _ in solve_nodes),
+                total_size=sum(int(node[1].size) for node in solve_nodes),
                 buckets=_size_buckets(
-                    [int(idx.size) for _, idx, _, _ in solve_nodes]
+                    [int(node[1].size) for node in solve_nodes]
                 ),
                 iterations=sum(r.iterations for r in results),
                 solve_seconds=t_solve.seconds,

@@ -20,8 +20,9 @@ to the fallback rung.  Only the health checks below and a raised
 :class:`~repro.guard.errors.SolverBreakdown` count as failed attempts: a
 JAX, XLA or Mosaic exception from a solve propagates to the caller.
 :func:`enforce_output` is the pipeline's graceful
-degradation closer: it guarantees valid labels, connected parts, and the
-weight corridor even when every spectral attempt failed.
+degradation closer: it guarantees valid labels even when every spectral
+attempt failed, and joins fragments and rebalances parts as far as the
+weight corridor allows.
 """
 
 from __future__ import annotations
@@ -259,9 +260,15 @@ def _balanced_reassign(n: int, nparts: int, weights) -> np.ndarray:
 def enforce_output(graph, parts, nparts: int, *, weights=None,
                    balance_tol: float = 0.05,
                    report: GuardReport | None = None) -> np.ndarray:
-    """Force the output invariant: valid labels, connected parts, weight
-    corridor.  Mutating repairs are recorded in ``report.degraded`` and
-    ``guard_fallbacks``; a no-op when the labeling is already valid."""
+    """Bring a labeling as close to the output invariant as the weight
+    corridor allows: labels always valid; fragments joined to a
+    neighbouring part wherever that part has room under the corridor's
+    cap (:func:`~repro.core.refine.repair_components` never trades the
+    corridor for connectivity); parts moved into the corridor as far as
+    ``_rebalance`` can.  What is left shows in :func:`check_output`
+    afterwards, as the finalize stage records.  Mutating repairs are
+    recorded in ``report.degraded`` and ``guard_fallbacks``; a no-op when
+    the labeling is already valid."""
     from repro.core.refine import repair_components
     from repro.core.multilevel import _rebalance
 

@@ -104,6 +104,19 @@ register("multilevel_levels", "gauge", agg="max",
          description="Coarse-to-fine warm-start hierarchy depth")
 
 # RSB level loop
+register("comp_split_nodes", "counter",
+         description="Tree nodes whose subgraph is disconnected, split by "
+                     "components instead of one Fiedler solve")
+register("grown_splits", "counter",
+         description="Tree nodes whose plain split left a half in pieces, "
+                     "split instead as unions of parts grown connected")
+register("ell_slots", "counter",
+         description="Padded ELL slots of the packed Laplacian, summed over "
+                     "the restart launches that gather over them")
+register("ell_nnz", "counter",
+         description="Stored off-diagonal Laplacian entries of the packed "
+                     "ELL, summed over the restart launches that gather "
+                     "over them")
 register("reorder_passes", "counter",
          description="Depth passes of the segmented RCB/RIB reorder, "
                      "one count per pass and level (⌈log₂ m⌉ for a level "
@@ -124,8 +137,8 @@ register("refine_sweeps", "counter",
          description="Boundary-refinement sweeps executed")
 register("fragments_repaired", "counter",
          description="Disconnected fragments reassigned by repair")
-register("forced_moves", "counter",
-         description="Repair moves that were balance-forced")
+register("repair_moved", "counter",
+         description="Node weight that repair moved into another part")
 
 # Multilevel k-way V-cycle (bisect="multilevel")
 register("ml_levels", "gauge", agg="max",
@@ -184,7 +197,7 @@ SPAN_NAMES = (
     # solver engines
     "engine", "solve", "split",
     # batched engine level loop, and the batched solve's host steps
-    "reorder", "sub", "warm_start", "pack", "restarts",
+    "reorder", "sub", "split_components", "warm_start", "pack", "restarts",
     # multilevel V-cycle
     "coarsen", "coarsest", "finalize",
     # host post chain

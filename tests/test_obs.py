@@ -323,7 +323,8 @@ def test_default_call_has_a_span_at_every_host_step(box_call):
     levels = [s for s in root.walk() if s.name.startswith("level:")]
     assert [s.name for s in levels] == ["level:0", "level:1", "level:2"]
     for lv in levels:
-        assert _children(lv) == ["reorder", "sub", "solve", "split"]
+        assert _children(lv) == ["reorder", "sub", "split_components",
+                                 "solve", "split"]
     for lv in levels[:2]:
         assert _children(lv.find("solve")) == ["warm_start", "pack",
                                                "restarts"]
@@ -336,6 +337,30 @@ def test_default_call_has_a_span_at_every_host_step(box_call):
     # A finished tree holds no profiler annotation: it pickles.
     copy = pickle.loads(pickle.dumps(root))
     assert [s.name for s in copy.walk()] == [s.name for s in root.walk()]
+
+
+def test_default_call_counts_components_repair_and_ell_slots(box_call):
+    """Each level's ``split_components`` span counts the nodes split by
+    components (none on a cube), each ``repair`` span the weight it moved,
+    and each ``pack`` span the padded ELL slots and stored entries over its
+    restart launches."""
+    mesh, ctx = box_call
+    root = ctx.trace
+    for lv in (s for s in root.walk() if s.name.startswith("level:")):
+        assert lv.find("split_components").counters == {
+            "comp_split_nodes": 0.0}
+    repairs = root.find_all("repair")
+    assert len(repairs) == 2            # the repair stage, refine's close
+    assert all(r.counters["repair_moved"] == 0.0 for r in repairs)
+    packs = root.find_all("pack")
+    assert len(packs) == 2              # levels 0 and 1
+    launches = [s.counters["restart_launches"]
+                for s in root.find_all("restarts")]
+    for pack, n in zip(packs, launches):
+        slots, nnz = pack.counters["ell_slots"], pack.counters["ell_nnz"]
+        # 512 packed rows, ELL width 32 (a hex element's 26 neighbours)
+        assert slots == 512 * 32 * n
+        assert 0 < nnz < slots and nnz % n == 0
 
 
 def test_restart_launches_count_the_restart_program(box_call, monkeypatch):
