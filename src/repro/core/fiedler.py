@@ -40,6 +40,7 @@ from repro.core.laplacian import (
     dense_laplacian_np,
     ell_laplacian_batched,
     fill_ell_block as _fill_ell_block,
+    spill_rows,
 )
 from repro.mesh.graphs import Graph, dual_graph_from_incidence
 
@@ -48,6 +49,27 @@ _DENSE_CUTOFF = 192
 
 def next_pow2(n: int) -> int:
     return 1 << max(0, (n - 1)).bit_length()
+
+
+def ell_layout(degrees: np.ndarray, n_rows: int) -> tuple[int, int]:
+    """(W, R): head width and overflow rows of a packed ELL Laplacian of
+    `n_rows` rows over nodes of these degrees (`EllLaplacian`'s layout).
+
+    The head is W = next_pow2(⌈mean degree⌉) slots wide, capped at the
+    plain width next_pow2(largest degree); R = next_pow2 of the overflow
+    rows its longer rows need.  The split is taken only where it gathers
+    at most half the plain layout's slots, (n_rows + R)·W ≤ n_rows·plain/2;
+    otherwise the plain layout (plain, 0), with no overflow at all."""
+    deg = np.asarray(degrees, np.int64)
+    plain = next_pow2(max(int(deg.max(initial=0)), 2))
+    if not deg.size:
+        return plain, 0
+    width = min(next_pow2(max(int(np.ceil(deg.mean())), 2)), plain)
+    need = int(spill_rows(deg, width).sum())
+    rows = next_pow2(need) if need else 0
+    if rows and 2 * (n_rows + rows) * width <= n_rows * plain:
+        return width, rows
+    return plain, 0
 
 
 @dataclasses.dataclass
@@ -537,21 +559,37 @@ def _pack_layout(sizes, pack_slots=None, pack_segs=None):
     return offs, N, n_seg, seg, mask
 
 
-def _packed_ell_laplacian(graphs: list, offs, N: int, width_pad: int) -> EllLaplacian:
+def _packed_ell_laplacian(graphs: list, offs, N: int, width_pad: int,
+                          overflow_rows: int = 0) -> EllLaplacian:
     """Block-diagonal ELL Laplacian over the packed slots (plain unbatched
     `EllLaplacian` of size N — each problem's cols are offset into its own
-    block, so there is no cross-problem coupling)."""
+    block, so there is no cross-problem coupling), with a head `width_pad`
+    wide and `overflow_rows` overflow rows for the hubs (`ell_layout`);
+    overflow rows left unfilled are padding (owner = col = N − 1, val 0)."""
     C = np.tile(np.arange(N, dtype=np.int64)[:, None], (1, width_pad))
     V = np.zeros((N, width_pad), dtype=np.float64)
     D = np.zeros(N, dtype=np.float64)
+    over = None
+    if overflow_rows:
+        over = (np.full((overflow_rows, width_pad), N - 1, dtype=np.int64),
+                np.zeros((overflow_rows, width_pad), dtype=np.float64),
+                np.full(overflow_rows, N - 1, dtype=np.int64))
+    at = 0
     for b, g in enumerate(graphs):
         o, o_next = int(offs[b]), int(offs[b + 1])
-        _fill_ell_block(g, C[o:o_next], V[o:o_next], D[o:o_next], col_offset=o)
+        at = _fill_ell_block(g, C[o:o_next], V[o:o_next], D[o:o_next],
+                             col_offset=o, over=over, at=at)
+    spill = {}
+    if over is not None:
+        spill = dict(over_cols=jnp.asarray(over[0].astype(np.int32)),
+                     over_vals=jnp.asarray(over[1].astype(np.float32)),
+                     over_owner=jnp.asarray(over[2].astype(np.int32)))
     return EllLaplacian(
         cols=jnp.asarray(C.astype(np.int32)),
         vals=jnp.asarray(V.astype(np.float32)),
         diag=jnp.asarray(D.astype(np.float32)),
         n=N,
+        **spill,
     )
 
 
@@ -667,6 +705,7 @@ def fiedler_from_graph_batched(
     pack_slots: int | None = None,
     pack_segs: int | None = None,
     width_pad: int | None = None,
+    overflow_rows: int | None = None,
     use_kernel: bool = False,
     multilevel: bool = True,
     precond: str = "jacobi",
@@ -679,12 +718,15 @@ def fiedler_from_graph_batched(
 
     method="lanczos" packs all subproblems into one flat block-diagonal
     solve whose trace is keyed by (pack_slots, pack_segs, width_pad,
-    window) — the RSB engine pins those to run-wide values so one trace
-    serves the whole run.  method="inverse" runs batched flexcg over
-    leading-batch-dim operators bucketed by (n_pad, width_pad), with
-    `precond="jacobi"` (the operator's own diagonal) or `precond="amg"`
-    (one packed `BatchedAMG` V-cycle per bucket — paper §7's
-    preconditioner, batched).  `use_kernel=True` routes BOTH layouts
+    overflow_rows, window) — the RSB engine pins those to run-wide values
+    so one trace serves the whole run.  The ELL layout (head width,
+    overflow rows: `ell_layout`) is this call's own where `width_pad` is
+    not given; a pinned layout keeps its width, and its overflow rows are
+    raised to the next power of two where this call's hubs need more.
+    method="inverse" runs batched flexcg over leading-batch-dim operators
+    bucketed by (n_pad, width_pad), with `precond="jacobi"` (the
+    operator's own diagonal) or `precond="amg"` (one packed `BatchedAMG`
+    V-cycle per bucket — paper §7's preconditioner, batched).  `use_kernel=True` routes BOTH layouts
     through the Pallas `ell_spmv` kernel: the packed 2-D operator uses the
     flat kernel and the 3-D leading-batch-dim operators use the batched
     grid variant.  `multilevel=True` (default) fills every missing `warms`
@@ -720,15 +762,16 @@ def fiedler_from_graph_batched(
             sizes = [graphs[i].n for i in solve_ix]
             offs, N, n_seg, seg, mask = _pack_layout(sizes, pack_slots,
                                                      pack_segs)
-            width = max(
-                int(graphs[i].degrees.max()) if graphs[i].nnz else 1
-                for i in solve_ix
-            )
-            width = next_pow2(max(width, 2))
-            if width_pad is not None:
-                width = max(width, int(width_pad))
-            op = _packed_ell_laplacian([graphs[i] for i in solve_ix], offs,
-                                       N, width)
+            solved = [graphs[i] for i in solve_ix]
+            deg = np.concatenate([g.degrees for g in solved])
+            if width_pad is None:
+                width, rows = ell_layout(deg, N)
+            else:
+                width, rows = int(width_pad), int(overflow_rows or 0)
+            spilled = int(spill_rows(deg, width).sum())
+            if spilled > rows:
+                rows = next_pow2(spilled)
+            op = _packed_ell_laplacian(solved, offs, N, width, rows)
             if use_kernel:
                 op = dataclasses.replace(op, use_kernel=True)
             b0 = _packed_b0(sizes, offs, N, [seeds[i] for i in solve_ix],
@@ -737,12 +780,14 @@ def fiedler_from_graph_batched(
         packed = _solve_packed_lanczos(
             op, offs, N, n_seg, seg, mask, b0, sizes, tol, window, max_restarts
         )
-        # Every restart launch gathers over all N × width slots once per
-        # Lanczos step: count the slots and the real entries among them.
+        # Every restart launch gathers over all (N + rows) × width slots
+        # once per Lanczos step: count the slots, the real entries among
+        # them and the overflow rows the hubs filled.
         launches = max(r.iterations for r in packed)
         if isinstance(t_pack, obs.Span):
-            for name, v in (("ell_slots", N * width),
-                            ("ell_nnz", sum(graphs[i].nnz for i in solve_ix))):
+            for name, v in (("ell_slots", (N + rows) * width),
+                            ("ell_nnz", sum(g.nnz for g in solved)),
+                            ("ell_overflow_rows", spilled)):
                 t_pack.counters[name] = (t_pack.counters.get(name, 0.0)
                                          + float(v * launches))
         for r, i in enumerate(solve_ix):

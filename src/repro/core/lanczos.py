@@ -19,11 +19,13 @@ segment matmul, while the small tridiagonal Ritz problems are solved with
 one vmapped `eigh` over the segment axis.  The operator is a block-diagonal
 *pytree* (`EllLaplacian`/`GSLaplacian` over the packed slots) passed as a
 traced argument, so the compiled trace is keyed only by
-(N, n_seg, window): because a tree level's subproblems partition the root
-set, every level of a run — and every run on the same mesh — reuses ONE
-trace, with no padded-lane compute.  Convergence is tracked per subproblem
-on the host; a converged problem's Ritz output is frozen while the
-remaining segments keep iterating.
+(N, n_seg, window) and the operator's shapes — for an ELL operator its
+head width W and overflow rows R (`fiedler.ell_layout`), which the RSB
+engine pins per run at the root: because a tree level's subproblems
+partition the root set, every level of a run — and every run on the same
+mesh — reuses ONE trace, with no padded-lane compute.  Convergence is
+tracked per subproblem on the host; a converged problem's Ritz output is
+frozen while the remaining segments keep iterating.
 """
 
 from __future__ import annotations
@@ -200,8 +202,9 @@ def _packed_restart(op, q, mask, seg, n_seg, window):
 
     `op` is a block-diagonal pytree operator over the packed (N,) slots,
     passed as a *traced* argument — the compile cache is keyed by
-    (N, n_seg, window), not by operator instance, so one trace serves every
-    level of a run (and every run sharing the shape).  Empty segments
+    (N, n_seg, window) and the operator's shapes (an ELL's (W, R)), not
+    by operator instance, so one trace serves every level of a run (and
+    every run sharing the shape).  Empty segments
     (padding) produce θ = 0, res = 0 and read as converged immediately.
     """
     m = window

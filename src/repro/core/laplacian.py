@@ -30,10 +30,21 @@ class EllLaplacian:
     level-synchronous RSB engine's layout).  Padding entries have val 0
     (col = row id).
 
-    Registered as a pytree (cols/vals/diag are leaves; n/use_kernel are
-    static) so a batched solve can take the operator as a *traced* jit
-    argument: one compiled trace serves every operator of the same shape
-    bucket instead of one trace per instance.
+    A 2-D operator may split its hub rows (the packed layout's
+    `repro.core.fiedler.ell_layout`): cols/vals are then the **head**,
+    each row's first `width` entries in CSR order, and the **overflow**
+    rows over_cols/over_vals (R, width) hold the rest of every longer row,
+    `width` entries per overflow row in CSR order, each summed into row
+    over_owner[r] (R,), ascending.  A hub's last overflow row pads with
+    col = owner, val 0; padding overflow rows have owner = col = n − 1 and
+    val 0, so they add 0.  With no overflow (R = 0) the three fields are
+    None: the operator, and every program traced over it, is the plain
+    layout's.  `fill_ell_block` writes these invariants.
+
+    Registered as a pytree (cols/vals/diag and the overflow fields are
+    leaves; n/use_kernel are static) so a batched solve can take the
+    operator as a *traced* jit argument: one compiled trace serves every
+    operator of the same shape bucket instead of one trace per instance.
     """
 
     cols: jax.Array    # (..., n, width) int32
@@ -41,6 +52,9 @@ class EllLaplacian:
     diag: jax.Array    # (..., n) float32 — Σ_j ω_ij (true Laplacian diagonal)
     n: int
     use_kernel: bool = False
+    over_cols: jax.Array | None = None   # (R, width) int32
+    over_vals: jax.Array | None = None   # (R, width) float32
+    over_owner: jax.Array | None = None  # (R,) int32, ascending
 
     def __hash__(self):
         return id(self)
@@ -59,8 +73,13 @@ class EllLaplacian:
         if self.use_kernel:
             from repro.kernels.ell_spmv import ops as _ops
 
-            return _ops.ell_spmv(self.cols, self.vals, x)
-        return (self.vals * jnp.take(x, self.cols, axis=-1)).sum(-1)
+            y = _ops.ell_spmv(self.cols, self.vals, x)
+        else:
+            y = (self.vals * jnp.take(x, self.cols, axis=-1)).sum(-1)
+        if self.over_owner is None:
+            return y
+        spill = (self.over_vals * jnp.take(x, self.over_cols, axis=-1)).sum(-1)
+        return y.at[..., self.over_owner].add(spill, indices_are_sorted=True)
 
     def apply(self, x: jax.Array) -> jax.Array:
         return self.diag * x - self.adj_apply(x)
@@ -71,24 +90,56 @@ class EllLaplacian:
 
 jax.tree_util.register_dataclass(
     EllLaplacian,
-    data_fields=("cols", "vals", "diag"),
+    data_fields=("cols", "vals", "diag", "over_cols", "over_vals",
+                 "over_owner"),
     meta_fields=("n", "use_kernel"),
 )
 
 
+def spill_rows(degrees: np.ndarray, width: int) -> np.ndarray:
+    """Overflow rows each node needs past an ELL head of `width` slots:
+    ⌈deg / width⌉ − 1, and 0 for a node of degree 0."""
+    return np.maximum(np.asarray(degrees, np.int64) - 1, 0) // width
+
+
 def fill_ell_block(graph: Graph, C: np.ndarray, V: np.ndarray, D: np.ndarray,
-                   col_offset: int = 0) -> None:
+                   col_offset: int = 0, over=None, at: int = 0) -> int:
     """Fill one graph's rows of a padded ELL block (C/V/D are views of the
     target rows; rows past graph.n keep self-columns and zero vals/diag,
     so L acts as 0 on them).  The single home of the padding invariants —
-    the padded, batched, and packed builders all delegate here."""
-    cols, vals = csr_to_ell(graph, max_row=None)
-    nb, wb = cols.shape
-    if wb > C.shape[1]:
-        raise ValueError("width_pad below max degree")
-    C[:nb, :wb] = cols + col_offset
-    V[:nb, :wb] = vals
-    np.add.at(D[:nb], graph.rows, graph.weights)
+    the padded, batched, and packed builders all delegate here.
+
+    Row i's first width = C.shape[1] entries, in CSR order, fill its head
+    row (the rest of the row: col = i + col_offset, val 0).  The entries
+    past them spill `width` at a time into the overflow rows `over` =
+    (cols, vals, owner), from row `at` on, owner i + col_offset (the
+    `EllLaplacian` layout); returns the next free overflow row.  Overflow
+    rows past the last one filled keep what the caller put there."""
+    width = C.shape[1]
+    extra = spill_rows(graph.degrees, width)
+    n_over = int(extra.sum())
+    if n_over and (over is None or at + n_over > over[2].shape[0]):
+        raise ValueError("ELL layout below the row degrees")
+    own = np.arange(graph.n, dtype=np.int64) + col_offset
+    rows = graph.rows
+    pos = np.arange(graph.nnz, dtype=np.int64) - graph.indptr[rows]
+    head = pos < width
+    C[:graph.n] = own[:, None]
+    V[:graph.n] = 0.0
+    C[rows[head], pos[head]] = graph.indices[head] + col_offset
+    V[rows[head], pos[head]] = graph.weights[head]
+    np.add.at(D[:graph.n], rows, graph.weights)
+    if n_over:
+        OC, OV, OO = over
+        OO[at:at + n_over] = np.repeat(own, extra)
+        OC[at:at + n_over] = OO[at:at + n_over, None]
+        OV[at:at + n_over] = 0.0
+        tail = ~head
+        first = at + np.cumsum(extra) - extra   # each node's first row
+        r = first[rows[tail]] + pos[tail] // width - 1
+        OC[r, pos[tail] % width] = graph.indices[tail] + col_offset
+        OV[r, pos[tail] % width] = graph.weights[tail]
+    return at + n_over
 
 
 def ell_laplacian_batched(

@@ -64,6 +64,7 @@ from repro import obs
 from repro.core import split
 from repro.core.fiedler import (
     _DENSE_CUTOFF,
+    ell_layout,
     fiedler_from_graph,
     fiedler_from_graph_batched,
     fiedler_from_mesh,
@@ -663,11 +664,11 @@ def _rsb_graph_batched(
           if guard is not None and guard.enabled else None)
     with obs.timed("engine", engine="batched") as t_total:
         # Run-wide shape-bucket pins (see _rsb_mesh_batched): subgraph degrees
-        # never exceed the root's, so the root ELL width bounds every level.
+        # never exceed the root's and a level's nodes are disjoint, so the
+        # root's ELL layout (head width, overflow rows) holds every level.
         pack_slots = next_pow2(max(n, 2))
         pack_segs = next_pow2(max(nparts, 1))
-        root_width = int(graph.degrees.max()) if graph.nnz else 1
-        width_pad = next_pow2(max(root_width, 2))
+        width_pad, overflow_rows = ell_layout(graph.degrees, pack_slots)
 
         # Each active node: (graph, element ids, p_lo, p_hi, plan, comp).
         # `plan` is the part plan a connected split left this node (None
@@ -754,7 +755,9 @@ def _rsb_graph_batched(
                             ],
                             tol=tol, window=window, max_restarts=max_restarts,
                             pack_slots=pack_slots, pack_segs=pack_segs,
-                            width_pad=width_pad, use_kernel=use_kernel,
+                            width_pad=width_pad,
+                            overflow_rows=overflow_rows,
+                            use_kernel=use_kernel,
                             multilevel=multilevel, precond=precond,
                         ) if problems else []
                 if sg is not None:

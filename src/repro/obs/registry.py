@@ -111,12 +111,17 @@ register("grown_splits", "counter",
          description="Tree nodes whose plain split left a half in pieces, "
                      "split instead as unions of parts grown connected")
 register("ell_slots", "counter",
-         description="Padded ELL slots of the packed Laplacian, summed over "
-                     "the restart launches that gather over them")
+         description="Padded ELL slots of the packed Laplacian, head and "
+                     "overflow rows, summed over the restart launches that "
+                     "gather over them")
 register("ell_nnz", "counter",
          description="Stored off-diagonal Laplacian entries of the packed "
                      "ELL, summed over the restart launches that gather "
                      "over them")
+register("ell_overflow_rows", "counter",
+         description="Overflow rows the hub rows of the packed ELL filled "
+                     "past its head (0 where the layout is plain), summed "
+                     "over the restart launches that gather over them")
 register("reorder_passes", "counter",
          description="Depth passes of the segmented RCB/RIB reorder, "
                      "one count per pass and level (⌈log₂ m⌉ for a level "

@@ -342,8 +342,8 @@ def test_default_call_has_a_span_at_every_host_step(box_call):
 def test_default_call_counts_components_repair_and_ell_slots(box_call):
     """Each level's ``split_components`` span counts the nodes split by
     components (none on a cube), each ``repair`` span the weight it moved,
-    and each ``pack`` span the padded ELL slots and stored entries over its
-    restart launches."""
+    and each ``pack`` span the padded ELL slots, stored entries and
+    overflow rows over its restart launches."""
     mesh, ctx = box_call
     root = ctx.trace
     for lv in (s for s in root.walk() if s.name.startswith("level:")):
@@ -361,6 +361,34 @@ def test_default_call_counts_components_repair_and_ell_slots(box_call):
         # 512 packed rows, ELL width 32 (a hex element's 26 neighbours)
         assert slots == 512 * 32 * n
         assert 0 < nnz < slots and nnz % n == 0
+        assert pack.counters["ell_overflow_rows"] == 0.0
+
+
+def test_a_hub_graph_call_counts_the_split_ell_slots():
+    """The default pipeline on an R-MAT graph with hubs (472 nodes, mean
+    degree 28.1, largest 314) in 8 parts: each ``pack`` span counts the
+    slots of the 512-row head and the overflow rows, (N + R)·W per launch,
+    and the top level spills every hub row past the head."""
+    from repro.configs.parrsb import make_pipeline
+    from repro.core.fiedler import ell_layout
+    from repro.core.laplacian import spill_rows
+    from repro.mesh.graphs import connected_labels, rmat_graph
+
+    g = rmat_graph(512, 12933, seed=4)
+    lab = connected_labels(g.n, g.rows, g.indices)
+    g = g.sub(np.flatnonzero(lab == np.argmax(np.bincount(lab))))
+    W, R = ell_layout(g.degrees, 512)
+    assert (g.n, W, R) == (472, 32, 256)
+    root = make_pipeline("default", guard=True).run(g, 8).trace
+    packs = root.find_all("pack")
+    launches = [s.counters["restart_launches"]
+                for s in root.find_all("restarts")]
+    assert len(packs) == len(launches) >= 2
+    for pack, n in zip(packs, launches):
+        assert pack.counters["ell_slots"] == (512 + R) * W * n
+        assert 0 < pack.counters["ell_overflow_rows"] <= R * n
+    top = packs[0].counters["ell_overflow_rows"]
+    assert top == spill_rows(g.degrees, W).sum() * launches[0]
 
 
 def test_restart_launches_count_the_restart_program(box_call, monkeypatch):

@@ -11,7 +11,9 @@ level-0 packed operator is n = 262,144 rows at ELL width 32, the level-1
 bucket is 2 × 131,072, and the sharded refinement's frontier is 128
 shards × 1,167 rows (padded to 1,168, in row blocks of 16) at width 26,
 the dual graph's max frontier degree, into 128 parts; the unbatched
-table takes a 4,096-row block of it.
+table takes a 4,096-row block of it.  The Lanczos restart program is
+compiled too, over the hub-split operator of the R-MAT cell (2,048 packed
+rows, a 64-slot head, 512 overflow rows, 16 segments, a 100-step window).
 
 The Pallas entry points are called with ``interpret=False`` directly:
 under ``JAX_PLATFORMS=cpu`` the ops' ``on_tpu()`` sees the CPU and would
@@ -89,3 +91,23 @@ def test_connection_table_batched_compiles(one_chip):
                                interpret=False), one_chip,
              ((SHARDS, m), jnp.int32), ((SHARDS, HALO, FRONTIER_W), jnp.int32),
              ((SHARDS, HALO, FRONTIER_W), jnp.float32))
+
+
+def test_packed_restart_over_a_hub_split_operator_compiles(one_chip):
+    from repro.core.lanczos import _packed_restart
+    from repro.core.laplacian import EllLaplacian
+
+    n, w, r = 2048, 64, 512
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    op = EllLaplacian(
+        cols=shape((n, w), jnp.int32), vals=shape((n, w), jnp.float32),
+        diag=shape((n,), jnp.float32), n=n,
+        over_cols=shape((r, w), jnp.int32), over_vals=shape((r, w), jnp.float32),
+        over_owner=shape((r,), jnp.int32))
+    vec = shape((n,), jnp.float32)
+    text = _packed_restart.lower(op, vec, vec, shape((n,), jnp.int32),
+                                 n_seg=16, window=100).compile().as_text()
+    assert "indices_are_sorted=true" in text
