@@ -1,4 +1,4 @@
-"""Benchmark driver: one module per paper table + kernels + roofline.
+"""Benchmark driver: one module per paper table.
 
 Prints ``name,us_per_call,derived`` CSV rows.
 
@@ -10,8 +10,6 @@ Tables ↔ paper:
   weak_scaling    — Table 4 (cube meshes, E/P const, message-size regime)
   quality         — §8 evaluation + §3 baselines (RSB/RCB/RIB/SFC/random),
                     including rsb_* rows for both engines
-  kernels         — Pallas kernel micro-benches
-  roofline        — §Roofline table from cached dry-run artifacts
 
 ``--json PATH`` writes the partition tables (plus an `engine_speedup`
 summary row — rsb_batched vs rsb_recursive wall clock — the
@@ -157,8 +155,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true", help="paper-scale sizes")
     ap.add_argument("--only", default=None,
                     choices=["partition", "partition_time", "weak_scaling",
-                             "quality", "kernels", "roofline"])
-    ap.add_argument("--dryrun-dir", default="runs/dryrun")
+                             "quality"])
     ap.add_argument("--json", default=None,
                     help="write partition tables to this BENCH json path")
     args = ap.parse_args()
@@ -200,11 +197,8 @@ def main() -> None:
             # the same bisection — check_dist_refine gates cut parity and
             # the one-collective-per-sweep contract on these rows.
             sharded_rows = _in_child("partition_time", "run_sharded()")
-    for name in ("weak_scaling", "kernels"):
-        if want(name):
-            _in_child(name, f"run(full={args.full})")
-    if want("roofline"):
-        _in_child("roofline_table", f"run({args.dryrun_dir!r})")
+    if want("weak_scaling"):
+        _in_child("weak_scaling", f"run(full={args.full})")
 
     if args.json:
         payload = {

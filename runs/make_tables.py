@@ -1,75 +1,10 @@
-"""Generate EXPERIMENTS.md markdown tables from dry-run/perf JSON records.
+"""Render a markdown summary table of the partition run manifests.
 
     PYTHONPATH=src python runs/make_tables.py
 """
 
 import glob
-import json
 import os
-
-ORDER_ARCH = ["deepseek-moe-16b", "qwen3-moe-30b-a3b", "mistral-large-123b",
-              "tinyllama-1.1b", "command-r-35b", "mace", "nequip",
-              "graphcast", "meshgraphnet", "sasrec"]
-ORDER_SHAPE = ["train_4k", "prefill_32k", "decode_32k", "long_500k",
-               "full_graph_sm", "minibatch_lg", "ogb_products", "molecule",
-               "train_batch", "serve_p99", "serve_bulk", "retrieval_cand"]
-
-
-def load(dirname="runs/dryrun"):
-    recs = {}
-    for f in glob.glob(os.path.join(dirname, "*.json")):
-        r = json.load(open(f))
-        key = (r["arch"], r["shape"], r.get("mesh", "skip"))
-        recs[key] = r
-    return recs
-
-
-def fmt_e(x):
-    return f"{x:.2e}"
-
-
-def roofline_table(recs, mesh="16x16"):
-    print(f"\n### Baseline roofline — single-pod {mesh} (256 chips)\n")
-    print("| arch | shape | kind | compute s | memory s | collective s | "
-          "dominant | useful | live GB/dev | fits 16GB |")
-    print("|---|---|---|---|---|---|---|---|---|---|")
-    for a in ORDER_ARCH:
-        for s in ORDER_SHAPE:
-            r = recs.get((a, s, mesh)) or recs.get((a, s, "skip"))
-            if r is None:
-                continue
-            if r.get("status") == "skip":
-                if mesh == "16x16":
-                    print(f"| {a} | {s} | — | — | — | — | SKIP | — | — | — |")
-                continue
-            if r.get("status") == "fail":
-                print(f"| {a} | {s} | — | — | — | — | FAIL | — | — | — |")
-                continue
-            rl = r["roofline"]
-            print(
-                f"| {a} | {s} | {r['kind']} | {fmt_e(rl['compute_s'])} | "
-                f"{fmt_e(rl['memory_s'])} | {fmt_e(rl['collective_s'])} | "
-                f"{rl['dominant']} | {rl['useful_fraction']:.2f} | "
-                f"{r['live_bytes_per_device']/1e9:.2f} | "
-                f"{'✓' if r['fits_16gb'] else '✗'} |"
-            )
-
-
-def multipod_table(recs):
-    print("\n### Multi-pod check — 2×16×16 (512 chips): compile + memory\n")
-    print("| arch | shape | status | live GB/dev | collective s | dominant |")
-    print("|---|---|---|---|---|---|")
-    for a in ORDER_ARCH:
-        for s in ORDER_SHAPE:
-            r = recs.get((a, s, "2x16x16"))
-            if r is None:
-                continue
-            if r.get("status") != "ok":
-                print(f"| {a} | {s} | {r.get('status')} | — | — | — |")
-                continue
-            rl = r["roofline"]
-            print(f"| {a} | {s} | ok | {r['live_bytes_per_device']/1e9:.2f} | "
-                  f"{fmt_e(rl['collective_s'])} | {rl['dominant']} |")
 
 
 def manifest_table(dirname="runs"):
@@ -112,17 +47,6 @@ def manifest_table(dirname="runs"):
               f"{solves} | {iters} |")
 
 
-def summary(recs):
-    ok = sum(1 for r in recs.values() if r.get("status") == "ok")
-    skip = sum(1 for r in recs.values() if r.get("status") == "skip")
-    fail = sum(1 for r in recs.values() if r.get("status") == "fail")
-    print(f"\ncells: ok={ok} skip={skip} fail={fail} "
-          f"(skips counted once, ok counted per mesh)")
-
 
 if __name__ == "__main__":
-    recs = load()
-    summary(recs)
-    roofline_table(recs, "16x16")
-    multipod_table(recs)
     manifest_table()

@@ -1,46 +1,2 @@
-"""Architecture registry: `--arch <id>` resolves here."""
-
-from repro.configs import (
-    command_r_35b,
-    deepseek_moe_16b,
-    graphcast,
-    mace,
-    meshgraphnet,
-    mistral_large_123b,
-    nequip,
-    qwen3_moe_30b_a3b,
-    sasrec,
-    tinyllama_1_1b,
-)
-from repro.configs.base import ArchDef, ShapeCell
-
-REGISTRY = {
-    m.ARCH.arch_id: m.ARCH
-    for m in (
-        deepseek_moe_16b,
-        qwen3_moe_30b_a3b,
-        mistral_large_123b,
-        tinyllama_1_1b,
-        command_r_35b,
-        mace,
-        nequip,
-        graphcast,
-        meshgraphnet,
-        sasrec,
-    )
-}
-
-
-def get_arch(arch_id: str) -> ArchDef:
-    if arch_id not in REGISTRY:
-        raise KeyError(
-            f"unknown arch {arch_id!r}; available: {sorted(REGISTRY)}"
-        )
-    return REGISTRY[arch_id]
-
-
-def all_cells():
-    """Every (arch × shape) cell with its skip reason (None = runnable)."""
-    for arch_id, arch in REGISTRY.items():
-        for shape_name, cell, skip in arch.cells():
-            yield arch_id, shape_name, cell, skip
+"""Partitioner configurations: :mod:`repro.configs.parrsb` holds the
+pipeline presets and :class:`~repro.configs.parrsb.ParRSBConfig`."""

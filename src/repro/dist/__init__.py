@@ -6,15 +6,13 @@ framework's communication:
 * :mod:`repro.dist.partition_aware` — halo sharding plans; a partition's
   edge cut becomes the all_gather volume of each message-passing sweep.
 * :mod:`repro.dist.collectives` — the distributed gather-scatter Laplacian
-  (paper §5 under shard_map) and a hand-rolled ring all-reduce reference.
-* :mod:`repro.dist.sharding` — logical-axis → mesh-axis PartitionSpec
-  rules for the LM / GNN / recsys model families.
+  (paper §5 under shard_map).
 * :mod:`repro.dist.refine_sharded` — device-resident sharded boundary
   refinement over the halo plan: one boundary-label all_gather per sweep,
   Pallas segment-sum gain tables (README: "Sharded refinement").
 """
 
-from repro.dist.collectives import dist_lap_apply_allreduce, ring_allreduce
+from repro.dist.collectives import dist_lap_apply_allreduce
 from repro.dist.partition_aware import (
     HaloPlan,
     adjacency_matvec_distributed,
@@ -32,36 +30,19 @@ from repro.dist.refine_sharded import (
     refine_sharded_stage,
     run_sharded_sweeps,
 )
-from repro.dist.sharding import (
-    MeshRules,
-    batch_specs_lm,
-    cache_specs_lm,
-    gnn_rules,
-    lm_rules,
-    param_specs_lm,
-    recsys_rules,
-)
 
 __all__ = [
     "FrontierPlan",
     "HaloPlan",
-    "MeshRules",
     "adjacency_matvec_distributed",
-    "batch_specs_lm",
     "build_frontier_plan",
-    "cache_specs_lm",
     "dist_lap_apply_allreduce",
     "gather_features",
-    "gnn_rules",
     "halo_exchange",
     "kway_sharded_stage",
-    "lm_rules",
-    "param_specs_lm",
     "plan_halo_sharding",
-    "recsys_rules",
     "refine_sharded_host",
     "refine_sharded_stage",
-    "ring_allreduce",
     "run_sharded_sweeps",
     "scatter_features",
     "verify_halo_plan",

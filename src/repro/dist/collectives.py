@@ -6,11 +6,6 @@ verbatim: each shard broadcasts its elements' values to their vertices
 ``segment_sum``), a single ``psum`` over the mesh axis completes the
 ``Q Qᵀ`` exchange, and a local ``take`` copies the global sums back.  The
 single-device reference is :mod:`repro.core.gather_scatter`.
-
-:func:`ring_allreduce` is the hand-rolled reference collective — a
-rotate-and-accumulate ring over ``jax.lax.ppermute`` whose N−1 steps each
-move one shard-sized buffer, matching ``psum`` exactly (used to validate
-the compiled collective and as the substrate for overlap experiments).
 """
 
 from __future__ import annotations
@@ -47,25 +42,3 @@ def dist_lap_apply_allreduce(gid: jax.Array, x_local: jax.Array,
     # Q + Pᵀ (local): copy global sums back, accumulate per element.
     aw_x = jnp.take(full, flat_gid).reshape(gid.shape).sum(axis=-1)
     return deg * x_local - aw_x
-
-
-def ring_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
-    """Sum ``x`` across the axis via an N−1-step ppermute ring.
-
-    Equivalent to ``jax.lax.psum(x, axis_name)``; each step rotates the
-    running buffer one hop and accumulates, so every link carries exactly
-    one buffer per step (the bandwidth-optimal ring schedule's volume,
-    without the reduce-scatter/all-gather split).
-    """
-    n = jax.lax.axis_size(axis_name)
-    perm = [(i, (i + 1) % n) for i in range(n)]
-
-    def body(_, carry):
-        acc, buf = carry
-        # The N−1 per-step hops ARE the ring schedule — this is the
-        # documented exception to one-collective-per-sweep.
-        buf = jax.lax.ppermute(buf, axis_name, perm)  # repro: ignore[DIST001]
-        return acc + buf, buf
-
-    acc, _ = jax.lax.fori_loop(1, n, body, (x, x))
-    return acc

@@ -30,7 +30,8 @@ Layout
   once, in its destination's shard.
 
 All planning is host-side NumPy (the ``gs_setup`` analogue); the arrays it
-produces feed jitted shard_map code here and in ``repro.models.gnn.halo``.
+produces feed the jitted shard_map code here and in
+:mod:`repro.dist.refine_sharded`.
 """
 
 from __future__ import annotations

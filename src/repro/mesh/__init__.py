@@ -10,10 +10,8 @@ from repro.mesh.graphs import (
     dual_graph,
     dual_graph_from_incidence,
     extract_subgraphs,
-    grid_coords_3d,
     grid_graph_2d,
     grid_graph_3d,
-    radius_molecule_batch,
     rmat_graph,
     stencil_graph_3d,
 )

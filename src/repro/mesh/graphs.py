@@ -1,7 +1,7 @@
 """Generic graph substrate: dual graphs, CSR/ELL utilities, generators.
 
 All construction is host-side NumPy (the `gs_setup` analogue); the arrays it
-produces are consumed by jitted JAX code in `repro.core` and `repro.models`.
+produces are consumed by jitted JAX code in `repro.core` and `repro.dist`.
 """
 
 from __future__ import annotations
@@ -305,7 +305,7 @@ def connected_components(graph: Graph) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Generators for the assigned GNN shape suite
+# Graph generators (tests, examples, benchmarks)
 # ---------------------------------------------------------------------------
 
 def grid_graph_2d(nx: int, ny: int) -> Graph:
@@ -333,11 +333,11 @@ def rmat_graph(
     seed: int = 0,
     batch: int = 1 << 22,
 ) -> Graph:
-    """R-MAT power-law generator (Chakrabarti et al.) — OGB-scale stand-in.
+    """R-MAT power-law generator (Chakrabarti et al.): skewed-degree test
+    graphs.
 
     Generates `n_edges` directed samples batch-wise (memory-lean), then
-    symmetrizes + coalesces.  Used for the `minibatch_lg` / `ogb_products`
-    shape cells where real datasets are unavailable offline.
+    symmetrizes + coalesces.
     """
     rng = np.random.default_rng(seed)
     levels = int(np.ceil(np.log2(max(n, 2))))
@@ -359,46 +359,9 @@ def rmat_graph(
     return build_csr(np.concatenate(srcs), np.concatenate(dsts), n)
 
 
-def radius_molecule_batch(
-    n_graphs: int,
-    n_nodes: int,
-    n_edges: int,
-    *,
-    seed: int = 0,
-    box: float = 4.0,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Batched random 3D point clouds with k-NN edges (molecule shape cell).
-
-    Returns (positions (G·V, 3), species (G·V,), edge_src, edge_dst) with
-    exactly `n_edges` directed edges per graph (k-NN truncated/padded) and
-    node ids offset per graph — the standard batched-small-graphs layout.
-    """
-    rng = np.random.default_rng(seed)
-    pos = rng.uniform(0, box, size=(n_graphs, n_nodes, 3))
-    species = rng.integers(0, 4, size=(n_graphs, n_nodes))
-    k = max(1, int(np.ceil(n_edges / n_nodes)))
-    d2 = ((pos[:, :, None, :] - pos[:, None, :, :]) ** 2).sum(-1)
-    d2 += np.eye(n_nodes)[None] * 1e9
-    nbr = np.argsort(d2, axis=-1)[:, :, :k]                    # (G, V, k)
-    src = np.tile(np.arange(n_nodes)[None, :, None], (n_graphs, 1, k))
-    src, nbr = src.reshape(n_graphs, -1), nbr.reshape(n_graphs, -1)
-    src, nbr = src[:, :n_edges], nbr[:, :n_edges]
-    offs = (np.arange(n_graphs, dtype=np.int64) * n_nodes)[:, None]
-    return (
-        pos.reshape(-1, 3),
-        species.reshape(-1),
-        (src + offs).ravel().astype(np.int64),
-        (nbr + offs).ravel().astype(np.int64),
-    )
-
-
 def stencil_graph_3d(nx: int, ny: int, nz: int, *, stencil: int = 26) -> Graph:
     """26- (or 6-) neighbor 3D stencil graph — the dual graph of a box hex
     mesh, built directly from offsets (memory-lean at millions of nodes).
-
-    At 135³ this reproduces the `ogb_products` cell scale (2.46M nodes,
-    ~63M directed edges) with spatial structure — representative of
-    GraphCast's icosahedral mesh (bounded degree, geometric locality).
     """
     idx = np.arange(nx * ny * nz, dtype=np.int64).reshape(nx, ny, nz)
     offs = [
@@ -431,9 +394,3 @@ def stencil_graph_3d(nx: int, ny: int, nz: int, *, stencil: int = 26) -> Graph:
     indptr = np.zeros(n + 1, dtype=np.int64)
     np.add.at(indptr, src + 1, 1)
     return Graph(n=n, indptr=np.cumsum(indptr), indices=dst, weights=w)
-
-
-def grid_coords_3d(nx: int, ny: int, nz: int) -> np.ndarray:
-    ii, jj, kk = np.meshgrid(np.arange(nx), np.arange(ny), np.arange(nz),
-                             indexing="ij")
-    return np.stack([ii.ravel(), jj.ravel(), kk.ravel()], 1).astype(np.float64)

@@ -31,7 +31,6 @@ from repro.obs.trace import (
     gauge_max,
     gauge_set,
     obs_enabled,
-    percentiles,
     render,
     set_enabled,
     span,
@@ -41,7 +40,7 @@ from repro.obs.trace import (
 
 __all__ = [
     "NOOP_SPAN", "Span", "counter_add", "current_span", "disabled",
-    "gauge_max", "gauge_set", "obs_enabled", "percentiles", "render",
+    "gauge_max", "gauge_set", "obs_enabled", "render",
     "set_enabled",
     "span", "timed", "trace",
     "MetricDef", "lookup", "merge_metrics", "register", "registered",

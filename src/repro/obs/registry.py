@@ -209,8 +209,6 @@ SPAN_NAMES = (
     "repair", "refine_sweeps", "repair_refine", "kway_fm",
     # sharded refinement
     "sharded_sweeps_total",
-    # serving path
-    "serve", "prefill", "decode_step",
 )
 
 SPAN_PREFIXES = (

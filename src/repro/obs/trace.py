@@ -253,7 +253,7 @@ NOOP_SPAN = _NoopSpan()
 
 def trace(name: str, **tags):
     """Open a span that may ROOT a new trace (use for whole-operation
-    scopes: one ``partition()`` call, one serve run).  Returns the
+    scopes: one ``partition()`` call).  Returns the
     :class:`Span` — keep it; the completed tree is what the exporters
     consume.  Disabled: a :class:`_Timer` (callers may still read
     ``.seconds``; ``PartitionContext.trace`` stays None-equivalent)."""
@@ -354,16 +354,3 @@ def render(root, *, max_depth: int = 4, min_share: float = 0.005) -> str:
 
     rec(root, 0)
     return "\n".join(lines)
-
-
-def percentiles(seconds: list, qs=(0.5, 0.99)) -> dict:
-    """p50/p99-style summary of a list of durations (serve-path span
-    histograms).  Nearest-rank; empty input → zeros."""
-    if not seconds:
-        return {f"p{int(q * 100)}": 0.0 for q in qs}
-    xs = sorted(seconds)
-    out = {}
-    for q in qs:
-        k = min(len(xs) - 1, max(0, int(round(q * (len(xs) - 1)))))
-        out[f"p{int(q * 100)}"] = xs[k]
-    return out

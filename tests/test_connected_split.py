@@ -20,6 +20,7 @@ from repro.core import (
     repair_components,
     split,
 )
+from repro.core.fiedler import _pack_layout, ell_layout
 from repro.core.rsb import rsb_partition_graph
 from repro.mesh import box_mesh, build_csr, grid_graph_2d
 from repro.mesh.graphs import connected_labels, rmat_graph, structural_order
@@ -169,18 +170,58 @@ def test_the_structural_order_follows_the_graph_not_its_labels():
     assert earlier.sum() == g.n - 1 and not earlier[a[0]]
 
 
-@pytest.mark.parametrize("seed", [1, 2])
-def test_a_graph_without_coordinates_is_solved_alike_in_any_order(seed):
+def _star_with_tail(leaves=120, tail=200):
+    """A hub with ``leaves`` leaves and a path of ``tail`` nodes hung off
+    it: its packed ELL spills the hub into overflow rows (R > 0)."""
+    path = np.arange(leaves + 1, leaves + tail + 1)
+    src = np.concatenate([np.zeros(leaves + 1, np.int64), path[:-1]])
+    dst = np.concatenate([np.arange(1, leaves + 1), path])
+    return build_csr(src, dst, leaves + tail + 1)
+
+
+def _edge_weighted_grid(nx=24, ny=16):
+    """A lattice with seeded integer edge weights 1–4."""
+    g = grid_graph_2d(nx, ny)
+    up = g.rows < g.indices
+    w = np.random.default_rng(7).integers(1, 5, int(up.sum())).astype(float)
+    return build_csr(g.rows[up], g.indices[up], g.n, weights=w)
+
+
+_ORDER_INPUTS = {
+    "rmat": _rmat_core,
+    "grid": lambda: grid_graph_2d(24, 16),
+    "star-tail": _star_with_tail,
+    "grid-edge-weighted": _edge_weighted_grid,
+}
+
+
+def test_the_star_with_a_tail_spills_into_overflow_rows():
+    g = _star_with_tail()
+    offs, N, *_ = _pack_layout([g.n])
+    W, R = ell_layout(g.degrees, N)
+    assert R > 0 and W < g.degrees.max()
+
+
+# A grid with node weights is left out: the structural order keys on
+# degrees and edge weights only, so its solves and cut follow the node
+# order (a known defect, named in ROADMAP.md).
+@pytest.mark.parametrize("name, nparts, seed", [
+    pytest.param(name, nparts, seed,
+                 id=str(seed) if (name, nparts) == ("rmat", 8)
+                 else f"{name}-{nparts}-{seed}")
+    for name in _ORDER_INPUTS for nparts in (4, 8, 16) for seed in (1, 2)])
+def test_a_graph_without_coordinates_is_solved_alike_in_any_order(
+        name, nparts, seed):
     """The same solves, restart for restart, and the same cut, whatever
     order the nodes come in."""
-    g = _rmat_core()
+    g = _ORDER_INPUTS[name]()
     perm = np.random.default_rng(seed).permutation(g.n)
-    p1, r1 = rsb_partition_graph(g, 8)
-    p2, r2 = rsb_partition_graph(g.sub(perm), 8)
+    p1, r1 = rsb_partition_graph(g, nparts)
+    p2, r2 = rsb_partition_graph(g.sub(perm), nparts)
     assert [(r.size, r.iterations) for r in r1.records] == \
         [(r.size, r.iterations) for r in r2.records]
     back = np.empty_like(p2)
     back[perm] = p2
-    assert partition_metrics(g, p1, 8).edge_cut == \
-        partition_metrics(g, back, 8).edge_cut
+    assert partition_metrics(g, p1, nparts).edge_cut == \
+        partition_metrics(g, back, nparts).edge_cut
 

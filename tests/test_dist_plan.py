@@ -119,8 +119,7 @@ def test_collective_words_tracks_cut():
 
 
 def test_pad_to_and_stats():
-    """benchmarks/hillclimb.py's contract: pad_to=8 lane alignment + a
-    JSON-able stats() record."""
+    """pad_to=8 lane alignment + a JSON-able stats() record."""
     import json
 
     g = grid_graph_2d(11, 11)                       # odd sizes everywhere

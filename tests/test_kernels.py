@@ -6,10 +6,6 @@ import pytest
 
 from repro.kernels.ell_spmv.ops import ell_spmv, ell_spmv_batched, lap_apply
 from repro.kernels.ell_spmv.ref import ell_spmv_batched_ref, ell_spmv_ref, lap_apply_ref
-from repro.kernels.embedding_bag.ops import embedding_bag as eb_kernel
-from repro.kernels.embedding_bag.ref import embedding_bag_ref
-from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import attention_ref
 
 RNG = np.random.default_rng(0)
 
@@ -99,87 +95,6 @@ def test_ell_kernel_used_by_fiedler():
     lam, _ = fiedler_oracle_np(g)
     res = fiedler_from_graph(g, method="lanczos", tol=1e-4, use_kernel=True)
     assert res.eigenvalue == pytest.approx(lam, rel=2e-2, abs=1e-4)
-
-
-@pytest.mark.parametrize("V,d,nnz,B", [(100, 16, 64, 10), (500, 50, 300, 32),
-                                       (64, 128, 128, 8)])
-def test_embedding_bag_sweep(V, d, nnz, B):
-    dtype = jnp.float32
-    table = jnp.asarray(RNG.normal(size=(V, d)), dtype)
-    idx = jnp.asarray(RNG.integers(0, V, nnz), jnp.int32)
-    seg = jnp.asarray(np.sort(RNG.integers(0, B, nnz)), jnp.int32)
-    out = eb_kernel(table, idx, seg, B, prefer="pallas")
-    ref = embedding_bag_ref(table, idx, seg, B)
-    visited = np.zeros(B, bool)
-    visited[np.asarray(seg)] = True
-    np.testing.assert_allclose(
-        np.asarray(out)[visited], np.asarray(ref)[visited], atol=1e-4
-    )
-
-
-def test_embedding_bag_weighted_and_unsorted():
-    V, d, nnz, B = 80, 24, 100, 12
-    table = jnp.asarray(RNG.normal(size=(V, d)), jnp.float32)
-    idx = jnp.asarray(RNG.integers(0, V, nnz), jnp.int32)
-    seg = jnp.asarray(RNG.integers(0, B, nnz), jnp.int32)  # UNsorted
-    wgt = jnp.asarray(RNG.normal(size=nnz), jnp.float32)
-    out = eb_kernel(table, idx, seg, B, weights=wgt, assume_sorted=False,
-                    prefer="pallas")
-    ref = embedding_bag_ref(table, idx, seg, B, weights=wgt)
-    visited = np.zeros(B, bool)
-    visited[np.asarray(seg)] = True
-    np.testing.assert_allclose(
-        np.asarray(out)[visited], np.asarray(ref)[visited], atol=1e-4
-    )
-
-
-@pytest.mark.parametrize(
-    "B,Sq,Skv,H,Hkv,D",
-    [
-        (2, 64, 64, 4, 2, 32),
-        (1, 100, 100, 4, 4, 64),
-        (2, 1, 200, 8, 2, 64),    # decode shape
-        (1, 128, 256, 4, 1, 32),  # continuation chunk
-        (1, 48, 48, 2, 2, 128),
-    ],
-)
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(B, Sq, Skv, H, Hkv, D, dtype):
-    q = jnp.asarray(RNG.normal(size=(B, Sq, H, D)), dtype)
-    k = jnp.asarray(RNG.normal(size=(B, Skv, Hkv, D)), dtype)
-    v = jnp.asarray(RNG.normal(size=(B, Skv, Hkv, D)), dtype)
-    out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
-                          prefer="pallas")
-    ref = attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32), **_tol(dtype)
-    )
-
-
-def test_flash_attention_noncausal():
-    q = jnp.asarray(RNG.normal(size=(2, 64, 4, 32)), jnp.float32)
-    k = jnp.asarray(RNG.normal(size=(2, 96, 2, 32)), jnp.float32)
-    v = jnp.asarray(RNG.normal(size=(2, 96, 2, 32)), jnp.float32)
-    out = flash_attention(q, k, v, causal=False, block_q=32, block_k=32,
-                          prefer="pallas")
-    ref = attention_ref(q, k, v, causal=False)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
-def test_flash_attention_matches_model_attention():
-    """Kernel ≡ the model's blocked_attention (same contraction)."""
-    from repro.models.transformer import blocked_attention
-
-    B, S, H, D = 2, 64, 4, 32
-    q = jnp.asarray(RNG.normal(size=(B, S, H, D)), jnp.float32)
-    k = jnp.asarray(RNG.normal(size=(B, S, H, D)), jnp.float32)
-    v = jnp.asarray(RNG.normal(size=(B, S, H, D)), jnp.float32)
-    pos = jnp.broadcast_to(jnp.arange(S), (B, S))
-    out_model = blocked_attention(q, k, v, q_pos=pos, block_q=16, block_kv=16)
-    out_kernel = flash_attention(q, k, v, causal=True, block_q=16,
-                                 block_k=16, prefer="pallas")
-    np.testing.assert_allclose(np.asarray(out_model), np.asarray(out_kernel),
-                               atol=2e-5)
 
 
 # ---------------------------------------------------------------------------

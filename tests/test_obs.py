@@ -288,14 +288,6 @@ def test_report_to_dict_round_trip(small_mesh):
     assert len(d["levels"]) == len(ctx.report.levels)
 
 
-def test_percentiles_nearest_rank():
-    secs = [float(i) for i in range(101)]
-    p = obs.percentiles(secs)
-    assert p["p50"] == 50.0
-    assert p["p99"] == 99.0
-    assert obs.percentiles([]) == {"p50": 0.0, "p99": 0.0}
-
-
 # ---------------------------------------------------------------------------
 # Host steps of the default pipeline, and the profiler annotations
 # ---------------------------------------------------------------------------

@@ -2,8 +2,12 @@
 (bit-for-bit refine="none" parity with the raw drivers), kwarg routing,
 presets, and the pipeline-output contract consumers rely on."""
 
+import dataclasses
+import functools
+
 import numpy as np
 import pytest
+from test_ell_split import _largest_component
 
 from repro.configs.parrsb import PIPELINE_PRESETS, make_pipeline
 from repro.core import (
@@ -15,7 +19,14 @@ from repro.core import (
     rsb_partition_mesh,
 )
 from repro.dist.partition_aware import plan_halo_sharding
-from repro.mesh import box_mesh, dual_graph, grid_graph_2d
+from repro.mesh import (
+    HexMesh,
+    box_mesh,
+    dual_graph,
+    grid_graph_2d,
+    pebble_mesh,
+)
+from repro.mesh.graphs import rmat_graph
 
 
 @pytest.fixture(scope="module")
@@ -75,18 +86,96 @@ def test_stage_records(default_ctx):
     assert "post" in stats
 
 
-@pytest.mark.parametrize("nparts", [1, 3, 5, 8, 16])
-def test_pipeline_nparts_parity(box, nparts):
+def _weighted(m):
+    """The mesh with seeded integer element weights 1–4."""
+    w = np.random.default_rng(5).integers(1, 5, m.nelems).astype(float)
+    return dataclasses.replace(m, weights=w)
+
+
+# name -> () -> (mesh or graph, node weights or None)
+_PARITY_INPUTS = {
+    "box": lambda: (box_mesh(8, 8, 4), None),
+    "box654": lambda: (box_mesh(6, 5, 4), None),
+    "box654-weighted": lambda: (_weighted(box_mesh(6, 5, 4)), None),
+    "pebble": lambda: (pebble_mesh(6, 6, 6, n_pebbles=2, seed=3), None),
+    "grid": lambda: (grid_graph_2d(16, 12), None),
+    "rmat": lambda: (_largest_component(rmat_graph(256, 2048, seed=3)), None),
+    "grid-weighted": lambda: (
+        grid_graph_2d(16, 12),
+        np.random.default_rng(6).integers(1, 5, 192).astype(float)),
+}
+
+
+@functools.cache
+def _parity_input(name):
+    return _PARITY_INPUTS[name]()
+
+
+# The R-MAT core in 16 parts is left out: the default pipeline leaves 5
+# of its parts disconnected (a known defect of the grown splits on hub
+# graphs, named in ROADMAP.md).
+_PARITY_CASES = [
+    pytest.param(name, nparts, id=str(nparts) if name == "box"
+                 else f"{name}-{nparts}")
+    for name in _PARITY_INPUTS for nparts in (1, 2, 3, 5, 8, 16)
+    if (name, nparts) != ("rmat", 16)
+]
+
+
+@pytest.mark.parametrize("name, nparts", _PARITY_CASES)
+def test_pipeline_nparts_parity(name, nparts):
     """Power-of-two and non-power-of-two nparts, plus the degenerate
-    single-part case, all balance and cover through the pipeline."""
-    m, g = box
+    single-part case, all balance and cover through the pipeline, on
+    meshes (cubic, non-cubic, weighted, carved) and on graphs without
+    coordinates (a lattice, a skewed-degree graph, node weights)."""
+    obj, weights = _parity_input(name)
+    if isinstance(obj, HexMesh):
+        g, w = dual_graph(obj), obj.weights
+    else:
+        g, w = obj, np.ones(obj.n) if weights is None else weights
     ctx = PartitionPipeline(bisect_kw=dict(tol=1e-2, max_restarts=10)).run(
-        m, nparts)
+        obj, nparts, weights=weights)
     assert set(np.unique(ctx.parts)) == set(range(nparts))
     pm = partition_metrics(g, ctx.parts, nparts)
     assert pm.disconnected_parts == 0
-    wsum = np.bincount(ctx.parts, weights=m.weights, minlength=nparts)
-    assert wsum.max() <= 1.06 * wsum.mean() + m.weights.max()
+    wsum = np.bincount(ctx.parts, weights=w, minlength=nparts)
+    assert wsum.max() <= 1.06 * wsum.mean() + w.max()
+
+
+# The multilevel preset on the weighted box is left out: it returns a
+# disconnected part with a clean guard report (a known defect, named in
+# ROADMAP.md).
+_PRESET_CASES = [
+    pytest.param(preset, name, id=f"{preset}-{name}")
+    for preset in PIPELINE_PRESETS for name in ("grid", "box654-weighted")
+    if (preset, name) != ("multilevel", "box654-weighted")
+]
+
+
+@pytest.mark.parametrize("preset, name", _PRESET_CASES)
+def test_every_preset_on_a_graph_and_a_weighted_mesh(preset, name):
+    """Every preset either partitions a graph without coordinates and a
+    weighted mesh within the contract, through its own stages and with a
+    clean guard report, or raises the error pinned for it."""
+    obj, _ = _parity_input(name)
+    pipe = make_pipeline(preset)
+    if (preset, name) == ("geometric", "grid"):
+        # A plain ValueError today, not a typed GuardError.
+        with pytest.raises(ValueError, match="bisect stages need coords") as info:
+            pipe.run(obj, 8)
+        assert type(info.value) is ValueError
+        return
+    ctx = pipe.run(obj, 8)
+    g, w = ((dual_graph(obj), obj.weights) if isinstance(obj, HexMesh)
+            else (obj, np.ones(obj.n)))
+    assert [(s.kind, s.name) for s in ctx.stages] == (
+        [("guard", "validate"), ("pre", pipe.pre), ("bisect", pipe.bisect)]
+        + [("post", p) for p in pipe.post] + [("guard", "finalize")])
+    assert ctx.report.guard.clean
+    assert set(np.unique(ctx.parts)) == set(range(8))
+    assert partition_metrics(g, ctx.parts, 8).disconnected_parts == 0
+    wsum = np.bincount(ctx.parts, weights=w, minlength=8)
+    assert wsum.max() <= 1.06 * wsum.mean() + w.max()
 
 
 def test_batch_of_one_matches_direct(box):

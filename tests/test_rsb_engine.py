@@ -3,6 +3,7 @@
 
 import numpy as np
 import pytest
+from test_connected_split import _rmat_core
 from test_rcb_segmented import _reference_segments
 
 from repro.configs.parrsb import make_pipeline
@@ -25,6 +26,7 @@ from repro.mesh import (
     grid_graph_2d,
     pebble_mesh,
 )
+from repro.mesh.graphs import connected_labels
 
 
 @pytest.fixture(scope="module")
@@ -286,3 +288,72 @@ def test_rib_engine_cut_parity_pebble(pebble):
     cr = partition_metrics(g, pr, 8).edge_cut
     assert cb <= 1.05 * cr and cr <= 1.05 * cb
     assert rb.pre == "rib"
+
+
+def _mesh_adjacency(m):
+    """Dense dual-graph adjacency of a hex mesh: ω = vertices shared."""
+    inc = np.zeros((m.nelems, m.n_vert))
+    inc[np.repeat(np.arange(m.nelems), m.vert_gid.shape[1]),
+        m.vert_gid.ravel()] = 1.0
+    a = inc @ inc.T
+    np.fill_diagonal(a, 0.0)
+    return a
+
+
+def _graph_adjacency(g):
+    a = np.zeros((g.n, g.n))
+    np.add.at(a, (g.rows, g.indices), g.weights)
+    return a
+
+
+def _tree_nodes(raw, nparts):
+    """(level, parts, elements) of each node of the bisection tree that
+    the raw labels imply, level by level and in part order."""
+    out, level, active = [], 0, [(0, nparts, np.arange(raw.size))]
+    while active:
+        nxt = []
+        for lo, hi, idx in active:
+            if hi - lo <= 1 or idx.size <= 1:
+                continue
+            out.append((level, hi - lo, idx))
+            mid = lo + (hi - lo) // 2
+            left = raw[idx] < mid
+            nxt += [(lo, mid, idx[left]), (mid, hi, idx[~left])]
+        active, level = nxt, level + 1
+    return out
+
+
+_LAMBDA2_INPUTS = {
+    "box666": lambda: box_mesh(6, 6, 6),
+    "grid": lambda: grid_graph_2d(24, 16),
+    "rmat": _rmat_core,
+}
+
+
+@pytest.mark.parametrize("nparts", [4, 16])
+@pytest.mark.parametrize("name", list(_LAMBDA2_INPUTS))
+def test_every_tree_node_lambda2_record_matches_dense_eigh(name, nparts):
+    """Each node's eigenvalue record against the float64 λ₂ of the
+    subgraph the node's elements induce (0 where it is disconnected,
+    relative to its mean weighted degree then): 1e-3 at the top node,
+    0.07 below it, the limits of the benchmark's check."""
+    obj = _LAMBDA2_INPUTS[name]()
+    adj = (_mesh_adjacency(obj) if hasattr(obj, "vert_gid")
+           else _graph_adjacency(obj))
+    ctx = make_pipeline("default").run(obj, nparts)
+    nodes = _tree_nodes(np.asarray(ctx.parts_raw), nparts)
+    records = ctx.report.records
+    assert [(r.level, r.nparts, r.size) for r in records] == \
+        [(lv, p, idx.size) for lv, p, idx in nodes]
+    errs = []
+    for (_, _, idx), r in zip(nodes, records):
+        sub = adj[np.ix_(idx, idx)]
+        rows, cols = np.nonzero(sub)
+        if np.unique(connected_labels(idx.size, rows, cols)).size > 1:
+            lam, scale = 0.0, sub.sum() / idx.size
+        else:
+            lam = np.linalg.eigvalsh(np.diag(sub.sum(1)) - sub)[1]
+            scale = lam
+        errs.append(abs(r.eigenvalue - lam) / scale)
+    assert errs[0] <= 1e-3
+    assert max(errs[1:]) <= 0.07
